@@ -175,12 +175,10 @@ def _sync_step_seconds(eng, mesh, axes, leaves, repeats=5):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     spec = [P() for _ in leaves]
-    fn = jax.jit(compat.shard_map(
-        lambda tree: eng.sync(tree), mesh, (spec,), spec,
-        check_vma=False, axis_names=frozenset(axes)))
+    fn = jax.jit(jax.shard_map(
+        lambda tree: eng.sync(tree), mesh=mesh, in_specs=(spec,),
+        out_specs=spec, check_vma=False, axis_names=frozenset(axes)))
     out = fn(leaves)
     jax.block_until_ready(out)
     best = float("inf")
@@ -203,15 +201,15 @@ def measured_section(smoke: bool, rows=None) -> None:
     import jax
     import numpy as np
 
-    from repro import compat
     from repro.core.calibrate import fit_link_params
+    from repro.launch.mesh import make_mesh
 
     if len(jax.devices()) < MEASURE_WORLD:
         print(f"overlap/measured,skip,needs {MEASURE_WORLD} devices,")
         return
     shape = (MEASURE_WORLD,)
     axes = ("data",)
-    mesh = compat.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
 
     d_model, n_layers, vocab = (256, 4, 4096) if smoke else (512, 8, 8192)
     specs = transformer_leaf_specs(d_model, n_layers, vocab)

@@ -40,7 +40,6 @@ def _measure_fn(mesh, axes, sizes, n_bytes):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core import collectives as C
 
     world = int(np.prod(sizes))
@@ -52,9 +51,10 @@ def _measure_fn(mesh, axes, sizes, n_bytes):
     spec = P(axes)
 
     def measure(schedule: str) -> float:
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v: C.all_reduce(v, schedule, axes, sizes),
-            mesh, spec, spec, check_vma=False, axis_names=frozenset(axes)))
+            mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
+            axis_names=frozenset(axes)))
         fn(x).block_until_ready()
         t0 = time.perf_counter()
         iters = 10

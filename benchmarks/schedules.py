@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as C
 
 
@@ -32,7 +32,7 @@ def run() -> None:
     if n_dev < 16:
         print(f"schedules,skip,needs 16 devices (have {n_dev})")
         return
-    mesh = compat.make_mesh((4, 4), ("a", "b"))
+    mesh = make_mesh((4, 4), ("a", "b"))
     axes, sizes = ("a", "b"), (4, 4)
     world = 16
 
@@ -44,8 +44,8 @@ def run() -> None:
         def make(schedule):
             def f(v):
                 return C.all_reduce(v, schedule, axes, sizes)
-            return jax.jit(compat.shard_map(
-                f, mesh, spec, spec,
+            return jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=spec, out_specs=spec,
                 check_vma=False, axis_names=frozenset(axes)))
 
         base = None
@@ -68,8 +68,8 @@ def run() -> None:
                 tok = jnp.ones((world, 1), jnp.float32)  # world-divisible
                 t = C.all_reduce(tok, schedule, axes, sizes)[0, 0]
             return v + t * 0
-        return jax.jit(compat.shard_map(
-            f, mesh, P(("a", "b")), P(("a", "b")),
+        return jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=P(("a", "b")), out_specs=P(("a", "b")),
             check_vma=False, axis_names=frozenset(axes)))
 
     for sched in ("fractal", "ring", "naive", "xla"):

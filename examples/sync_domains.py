@@ -22,14 +22,14 @@ import jax.numpy as jnp        # noqa: E402
 import numpy as np             # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat                     # noqa: E402
+from repro.launch.mesh import make_mesh     # noqa: E402
 from repro.core import collectives as C      # noqa: E402
 from repro.core.barrier import SyncDomainMesh  # noqa: E402
 from repro.core.tree import FractalTree      # noqa: E402
 
 
 def main():
-    mesh = compat.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     sdm = SyncDomainMesh(mesh, ("pod", "data"))
     tree = sdm.tree
     print(f"mesh {dict(mesh.shape)} → {tree.num_levels}-level sync tree")
@@ -53,9 +53,9 @@ def main():
                 perm = [(i, i ^ (1 << b)) for i in range(8)]
                 red = red + jax.lax.ppermute(red, axes, perm)
             return red + 0 * tok
-        return jax.jit(compat.shard_map(f, mesh, spec, spec,
-                                        check_vma=False,
-                                        axis_names=frozenset(("pod", "data"))))(x)
+        return jax.jit(jax.shard_map(
+            f, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
+            axis_names=frozenset(("pod", "data"))))(x)
 
     for level in (1, 2, 3):
         out = np.asarray(run(level)).ravel()

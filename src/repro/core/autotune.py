@@ -60,27 +60,14 @@ class BucketPolicy:
 # term only, so small latency-bound buckets never win from compression — the
 # per-bucket policy the ROADMAP asks for falls out of the pricing.
 CODEC_WIRE_RATIO = {"none": 1.0, "bf16": 0.5, "int8": (1.0 + 4.0 / 128) / 4.0}
-CODEC_STEP_ALPHAS = {"none": 0.0, "bf16": 1.0, "int8": 2.0}
-# With the codec-fused tree_reduce/decode_add kernels (dequant folded into
-# the receive-side accumulate, one launch instead of dequant-then-add) each
-# exchange pays one launch fewer: bf16's encode is a cast XLA fuses into the
+# The codec-fused tree_reduce/decode_add kernels fold the dequant into the
+# receive-side accumulate (collectives route receive hops through
+# ``kernels.tree_reduce.ops.decode_add``), so each exchange pays one launch
+# fewer than dequant-then-add: bf16's encode is a cast XLA fuses into the
 # send slice (decode side free → 0.5 total), int8 still pays its quant
-# kernel but the dequant launch disappears (2.0 → 1.0).
-CODEC_STEP_ALPHAS_FUSED = {"none": 0.0, "bf16": 0.5, "int8": 1.0}
+# kernel but the dequant launch disappears (1.0).
+CODEC_STEP_ALPHAS = {"none": 0.0, "bf16": 0.5, "int8": 1.0}
 CODECS = tuple(CODEC_WIRE_RATIO)
-
-
-def codec_step_alphas() -> dict:
-    """Per-step codec launch-overhead table for THIS install: the fused
-    table when the Pallas kernels actually dispatch (collectives then route
-    receive hops through ``kernels.tree_reduce.ops.decode_add``), the
-    unfused one on reference installs.  Every codec-pricing consumer
-    (``rank_policies``, ``SuperstepEngine.timeline``) reads this resolver so
-    the calibrated tuner re-prices automatically when fusion is available.
-    """
-    from repro.kernels import kernels_backend
-    return (CODEC_STEP_ALPHAS_FUSED if kernels_backend() == "pallas"
-            else CODEC_STEP_ALPHAS)
 
 
 @lru_cache(maxsize=512)
@@ -261,9 +248,8 @@ def rank_policies(shape: Sequence[int], payload_bytes: float,
 
     Codecs ride the fractal schedule's point-to-point exchanges (that is the
     only lowering with wire compression), shrinking the bandwidth term by
-    ``CODEC_WIRE_RATIO`` while paying ``codec_step_alphas()`` extra launch
-    latencies per step for the quant/dequant kernels (the fused table when
-    the codec-fused tree_reduce kernels dispatch).  Under
+    ``CODEC_WIRE_RATIO`` while paying ``CODEC_STEP_ALPHAS`` extra launch
+    latencies per step for the codec-fused quant/dequant kernels.  Under
     ``zero1_publish`` only the reduce-scatter half compresses — the
     all-gather half publishes full-precision parameters.
     """
@@ -276,14 +262,13 @@ def rank_policies(shape: Sequence[int], payload_bytes: float,
     if "fractal" in dict(ranking) and math.prod(shape) > 1:
         prog = schedule_ir.build_program("fractal", shape)
         base = dict(ranking)["fractal"]
-        alphas = codec_step_alphas()
         for codec in codecs:
             if codec == "none":
                 continue
             wire = cost_model.program_cost_banded(
                 prog, payload_bytes * CODEC_WIRE_RATIO[codec], link,
                 outer_link, mesh_contention)
-            overhead = alphas[codec] * link.alpha_s * prog.num_steps
+            overhead = CODEC_STEP_ALPHAS[codec] * link.alpha_s * prog.num_steps
             if zero1_publish:
                 # only the reduce-scatter half carries the codec — both
                 # the wire saving AND the quant launches halve

@@ -27,7 +27,6 @@ from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 
-from repro import compat
 
 from . import collectives
 from .barrier import barrier_tie
@@ -177,6 +176,6 @@ def bsp_shard_map(fn: Callable, mesh: jax.sharding.Mesh,
     every other mesh axis (e.g. "model") stays auto (GSPMD).
     """
     del auto_axes  # everything not in sync_axes is auto by construction
-    return compat.shard_map(fn, mesh, in_specs, out_specs,
-                            check_vma=False,
-                            axis_names=frozenset(sync_axes))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False,
+                         axis_names=frozenset(sync_axes))

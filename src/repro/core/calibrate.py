@@ -133,16 +133,16 @@ def _measure_collective(mesh, axis_names: Tuple[str, ...],
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from . import collectives as C
 
     world = math.prod(sizes)
     spec = P(axis_names)
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(per_rank_elems * world,)).astype(np.float32))
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda v: C.all_reduce(v, schedule, axis_names, sizes),
-        mesh, spec, spec, check_vma=False, axis_names=frozenset(axis_names)))
+        mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False,
+        axis_names=frozenset(axis_names)))
     fn(x).block_until_ready()      # compile outside the timed region
     best = float("inf")
     for _ in range(repeats):
@@ -170,7 +170,7 @@ def fit_link_params(shape: Optional[Tuple[int, ...]] = None,
     """
     import jax
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     n_dev = len(jax.devices())
     if shape is None:
@@ -182,7 +182,7 @@ def fit_link_params(shape: Optional[Tuple[int, ...]] = None,
             f"link calibration needs ≥{min_devices} devices, have {n_dev} "
             f"(mesh {shape}); set --devices / XLA_FLAGS host-device count")
     axis_names = tuple(f"cal{i}" for i in range(len(shape)))
-    mesh = compat.make_mesh(shape, axis_names)
+    mesh = make_mesh(shape, axis_names)
     samples: List[LinkSample] = []
     for schedule in schedules:
         for elems in payload_elems:

@@ -105,7 +105,7 @@ def _codec_exchange_add(keep, send, axis_names: AxisNames, perm, codec):
     With a codec, the wire-decode is fused into the accumulate via
     ``kernels.tree_reduce.ops.decode_add`` (one launch instead of
     dequant-then-add; the fused per-step α that
-    ``autotune.CODEC_STEP_ALPHAS_FUSED`` prices).  Off-TPU ``decode_add``
+    ``autotune.CODEC_STEP_ALPHAS`` prices).  Off-TPU ``decode_add``
     IS ``keep + codec.decode(wire)``, so CPU numerics are bit-identical
     to the unfused expression the collective tests pin."""
     if codec is None:

@@ -444,8 +444,7 @@ class SuperstepEngine:
         their quant/dequant launch overhead — the same terms the policy
         pricing (``autotune.rank_policies``) chose them by.
         """
-        from .autotune import CODEC_WIRE_RATIO, codec_step_alphas
-        alphas = codec_step_alphas()
+        from .autotune import CODEC_STEP_ALPHAS as alphas, CODEC_WIRE_RATIO
         link = link if link is not None else self.link
         total_raw = max(1, sum(b.raw for b in self.buckets))
         ready, cum = [], 0

@@ -1,22 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels: each package holds the kernel, its public op and a
+pure-jnp reference the tests compare against."""
 
-def kernels_backend() -> str:
-    """Which implementation the public ops dispatch to on this install.
+import jax
 
-    "pallas"   — the Pallas kernels (native on TPU, interpret elsewhere)
-    "reference"— pure-jnp oracles (Pallas API unsupported by installed jax)
 
-    Reads the ops modules' own dispatch flags so this answer can never
-    disagree with what the ops actually run (a kernel module import can
-    fail independently of the coarse API probe in ``compat``).
-    """
-    from repro.kernels.flash_attention import ops as _fa
-    from repro.kernels.gemm import ops as _gemm
-    from repro.kernels.paged_attention import ops as _pa
-    from repro.kernels.tree_reduce import ops as _tr
-    pallas = (_gemm._PALLAS_OK and _fa._PALLAS_OK and _tr._PALLAS_OK
-              and _pa._PALLAS_OK)
-    return "pallas" if pallas else "reference"
-
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU.  The ops run their kernels
+    natively there and in Pallas interpret mode everywhere else."""
+    return jax.default_backend() == "tpu"

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -114,7 +113,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
             pltpu.VMEM((block_q, Dv), jnp.float32),  # acc
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -15,12 +15,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.compat import import_pallas_kernels, on_tpu as _on_tpu
+from repro.kernels import on_tpu
 
+from .kernel import flash_attention_pallas
 from .ref import flash_attention_ref
-
-flash_attention_pallas, _PALLAS_OK = import_pallas_kernels(
-    "repro.kernels.flash_attention.kernel", "flash_attention_pallas")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -29,9 +27,6 @@ def _flash(q, k, v, causal, window, softcap, interpret):
 
 
 def _fwd_impl(q, k, v, causal, window, softcap, interpret):
-    if not _PALLAS_OK:
-        return flash_attention_ref(q, k, v, causal=causal, window=window,
-                                   softcap=softcap)
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     bq = min(128, Tq) if Tq % 128 else 128
@@ -68,7 +63,7 @@ _flash.defvjp(_fwd, _bwd)
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     softcap=None, interpret: bool | None = None):
     """q: [B,Tq,Hq,D], k/v: [B,Tk,Hkv,D] → [B,Tq,Hq,D] (GQA grouped)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = (not on_tpu()) if interpret is None else interpret
     B, Tq, Hq, D = q.shape
     _, Tk, Hkv, Dv = v.shape
     G = Hq // Hkv

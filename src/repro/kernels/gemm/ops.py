@@ -1,5 +1,5 @@
-"""jit'd public wrapper for the GEMM kernel: padding + dtype policy +
-interpret fallback on non-TPU backends."""
+"""jit'd public wrapper for the GEMM kernel: padding + dtype policy;
+interpret mode on non-TPU backends."""
 
 from __future__ import annotations
 
@@ -8,12 +8,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import import_pallas_kernels, on_tpu as _on_tpu
+from repro.kernels import on_tpu
 
+from .kernel import gemm_pallas
 from .ref import gemm_ref
-
-gemm_pallas, _PALLAS_OK = import_pallas_kernels(
-    "repro.kernels.gemm.kernel", "gemm_pallas")
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
@@ -21,12 +19,8 @@ gemm_pallas, _PALLAS_OK = import_pallas_kernels(
 def gemm(x: jax.Array, y: jax.Array, *, block_m: int = 128,
          block_n: int = 128, block_k: int = 128,
          interpret: bool | None = None) -> jax.Array:
-    """Padded blocked GEMM. interpret=None → auto (interpret off-TPU).
-    Falls back to the jnp reference when the installed Pallas lacks the API
-    the kernel needs (guarded import above)."""
-    if not _PALLAS_OK:
-        return gemm_ref(x, y)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    """Padded blocked GEMM. interpret=None → auto (interpret off-TPU)."""
+    interpret = (not on_tpu()) if interpret is None else interpret
     M, K = x.shape
     _, N = y.shape
     pm = (-M) % block_m

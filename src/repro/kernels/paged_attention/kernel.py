@@ -13,9 +13,9 @@ barrier design applied to data movement).
 
 Two variants, both single-query (T == 1 decode):
 
-* ``paged_attention_pallas``     — GQA: grid (B, Hkv, n), per-(batch, kv
-  head) program streams the row's blocks and reduces G grouped query
-  heads at once.
+* ``paged_attention_pallas``     — GQA: grid (B, n), per-batch-row
+  program streams the row's blocks, all kv heads per block, and reduces
+  each head's G grouped query heads at once.
 * ``paged_mla_attention_pallas`` — MLA absorbed decode: grid (B, n);
   scores are latent-space (q_eff·c_kv + q_rope·k_rope) and the streamed
   c_kv block doubles as the value matrix.
@@ -37,16 +37,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 NEG_INF = -1e30
 
 
 def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, bs: int, n: int,
-                  window, softcap):
+                  hkv: int, d: int, dv: int, window, softcap):
     b = pl.program_id(0)
-    j = pl.program_id(2)               # kv block step (innermost)
+    j = pl.program_id(1)               # kv block step (innermost)
 
     @pl.when(j == 0)
     def _init():
@@ -58,76 +56,80 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when((j * bs) < length)
     def _step():
-        q = q_ref[0, 0]                               # [G, d]
-        k = k_ref[0, :, 0, :]                         # [bs, d]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        G = s.shape[0]
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
-        mask = pos < length
-        if window is not None:
-            # query sits at virtual position length-1
-            mask &= (length - 1 - pos) < window
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(hkv):           # every kv head of the block, in turn
+            q = q_ref[0, h]                               # [G, d]
+            k = k_ref[0, :, h * d:(h + 1) * d]            # [bs, d]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if softcap is not None:
+                s = softcap * jnp.tanh(s / softcap)
+            G = s.shape[0]
+            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (G, bs), 1)
+            mask = pos < length
+            if window is not None:
+                # query sits at virtual position length-1
+                mask &= (length - 1 - pos) < window
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p.astype(v_ref.dtype), v_ref[0, :, h * dv:(h + 1) * dv],
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == n - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pool, v_pool, tables, lengths, *,
                            scale: float, window=None, softcap=None,
                            interpret: bool = False):
     """q: [B, Hkv, G, d], pools: [N, bs, Hkv, d(v)], tables: [B, n] int32,
-    lengths: [B] int32 → [B, Hkv, G, dv].  ops.py does the GQA reshape."""
+    lengths: [B] int32 → [B, Hkv, G, dv].  ops.py does the GQA reshape.
+
+    The pools are viewed as [N, bs, Hkv*d] (a free reshape), so each grid
+    step streams one whole physical block, every kv head at once, as a
+    [bs, Hkv*d] tile whose head slices sit on 128-lane boundaries."""
     B, Hkv, G, d = q.shape
     N, bs = k_pool.shape[:2]
     dv = v_pool.shape[-1]
     n = tables.shape[1]
     kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, n=n,
-                               window=window, softcap=softcap)
+                               hkv=Hkv, d=d, dv=dv, window=window,
+                               softcap=softcap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, n),
+        grid=(B, n),
         in_specs=[
-            pl.BlockSpec((1, 1, G, d),
-                         lambda b, h, j, tables, lengths: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda b, h, j, tables, lengths:
-                         (tables[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, dv),
-                         lambda b, h, j, tables, lengths:
-                         (tables[b, j], 0, h, 0)),
+            pl.BlockSpec((1, Hkv, G, d),
+                         lambda b, j, tables, lengths: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv * d),
+                         lambda b, j, tables, lengths: (tables[b, j], 0, 0)),
+            pl.BlockSpec((1, bs, Hkv * dv),
+                         lambda b, j, tables, lengths: (tables[b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, dv),
-                               lambda b, h, j, tables, lengths:
-                               (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, dv),
+                               lambda b, j, tables, lengths: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # m
-            pltpu.VMEM((G, 1), jnp.float32),    # l
-            pltpu.VMEM((G, dv), jnp.float32),   # acc
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # m
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # l
+            pltpu.VMEM((Hkv, G, dv), jnp.float32),   # acc
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, lengths, q, k_pool, v_pool)
+    )(tables, lengths, q, k_pool.reshape(N, bs, Hkv * d),
+      v_pool.reshape(N, bs, Hkv * dv))
 
 
 def _paged_mla_kernel(tables_ref, lengths_ref, qe_ref, qr_ref, ckv_ref,
@@ -209,7 +211,7 @@ def paged_mla_attention_pallas(q_eff, q_rope, ckv_pool, kr_pool, tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_eff.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, q_eff, q_rope, ckv_pool, kr_pool)

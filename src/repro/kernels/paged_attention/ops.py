@@ -1,12 +1,8 @@
 """Public fused paged-attention decode ops: GQA grouping + dispatch.
 
 Decode-only (T == 1), forward-only (no grads flow at serve time), so no
-custom_vjp is needed — dispatch is a straight three-way switch shared
-with the other kernel packages:
-
-  * TPU            → native Pallas kernel (block-table scalar prefetch)
-  * elsewhere      → the same kernel in interpret mode
-  * Pallas missing → the jnp gather-then-attend reference
+custom_vjp is needed.  The kernel runs natively on TPU and in interpret
+mode elsewhere; the jnp gather-then-attend reference lives in ``ref.py``.
 
 ``models/layers.py`` routes its paged T==1 decode branch here when the
 resolved ``paged_kernel`` knob says "pallas"; the ``paged_gather`` path
@@ -19,14 +15,10 @@ import math
 
 import jax.numpy as jnp
 
-from repro.compat import import_pallas_kernels, on_tpu
+from repro.kernels import on_tpu
 
+from .kernel import paged_attention_pallas, paged_mla_attention_pallas
 from .ref import paged_attention_ref, paged_mla_attention_ref
-
-(paged_attention_pallas, paged_mla_attention_pallas,
- _PALLAS_OK) = import_pallas_kernels(
-    "repro.kernels.paged_attention.kernel",
-    "paged_attention_pallas", "paged_mla_attention_pallas")
 
 
 def _lengths(offset, batch: int):
@@ -56,14 +48,10 @@ def paged_attention(q, k_pool, v_pool, tables, offset, *, scale=None,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     qh = q[:, 0].reshape(B, Hkv, G, d)
     lengths = _lengths(offset, B)
-    if not _PALLAS_OK:
-        o = paged_attention_ref(qh, k_pool, v_pool, tables, lengths,
-                                scale=scale, window=window, softcap=softcap)
-    else:
-        interpret = (not on_tpu()) if interpret is None else interpret
-        o = paged_attention_pallas(qh, k_pool, v_pool, tables, lengths,
-                                   scale=scale, window=window,
-                                   softcap=softcap, interpret=interpret)
+    interpret = (not on_tpu()) if interpret is None else interpret
+    o = paged_attention_pallas(qh, k_pool, v_pool, tables, lengths,
+                               scale=scale, window=window, softcap=softcap,
+                               interpret=interpret)
     return o.reshape(B, 1, Hq, v_pool.shape[-1])
 
 
@@ -84,14 +72,9 @@ def paged_mla_attention(q_eff, q_rope, ckv_pool, kr_pool, tables, offset, *,
     qr = q_rope[:, 0]
     kr = kr_pool[:, :, 0, :] if kr_pool.ndim == 4 else kr_pool
     lengths = _lengths(offset, B)
-    if not _PALLAS_OK:
-        o = paged_mla_attention_ref(qe, qr, ckv_pool, kr, tables, lengths,
-                                    scale=scale)
-    else:
-        interpret = (not on_tpu()) if interpret is None else interpret
-        o = paged_mla_attention_pallas(qe, qr, ckv_pool, kr, tables,
-                                       lengths, scale=scale,
-                                       interpret=interpret)
+    interpret = (not on_tpu()) if interpret is None else interpret
+    o = paged_mla_attention_pallas(qe, qr, ckv_pool, kr, tables, lengths,
+                                   scale=scale, interpret=interpret)
     return o[:, None]
 
 

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 
 def _tree_reduce_kernel(x_ref, o_ref, *, levels: int):
     acc = x_ref[...].astype(jnp.float32)      # [N, block]
@@ -55,7 +53,7 @@ def tree_reduce_pallas(x: jax.Array, *, block: int = 512,
         in_specs=[pl.BlockSpec((N, block), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, block), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, D), out_dtype or x.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
@@ -67,19 +65,25 @@ def tree_reduce_pallas(x: jax.Array, *, block: int = 512,
 # ---------------------------------------------------------------------------
 
 
+# int8 codec blocks are tiled ROWS codec blocks at a time: a (ROWS, 128)
+# int8 tile is one native (32, 128) int8 vreg tile on TPU, and a block's
+# second-minor dim must be a multiple of 8 unless it spans the whole array
+ROWS = 32
+
+
 def _int8_tree_reduce_kernel(q_ref, s_ref, o_ref, *, levels: int):
-    """One 128-lane codec block: dequant q·scale in VMEM, then the same
+    """ROWS 128-lane codec blocks: dequant q·scale in VMEM, then the same
     pairwise halving as ``_tree_reduce_kernel``.  H-tree order is
     preserved; only the dequant multiply may fuse into the first add
     (FMA), so fused vs dequant-then-reduce agree to an ulp, and the
     reduction stays deterministic in worker count."""
-    acc = q_ref[:, 0, :].astype(jnp.float32) * s_ref[:, 0, :]   # [N, 128]
+    acc = q_ref[...].astype(jnp.float32) * s_ref[...]      # [N, ROWS, 128]
     n = acc.shape[0]
     for _ in range(levels):
         half = n // 2
         acc = acc[:half] + acc[half:n]
         n = half
-    o_ref[...] = acc[:1].astype(o_ref.dtype)
+    o_ref[...] = acc[0].astype(o_ref.dtype)
 
 
 def int8_tree_reduce_pallas(q: jax.Array, scale: jax.Array, *,
@@ -87,24 +91,29 @@ def int8_tree_reduce_pallas(q: jax.Array, scale: jax.Array, *,
                             interpret: bool = False) -> jax.Array:
     """q: [N, nb, 128] int8 + scale: [N, nb, 1] f32 (per-row, per-128-lane
     codec blocks) → [nb*128] tree sum of the dequantized rows, one launch.
-    N must be a power of two (ops.py pads with zero wire rows)."""
+    N must be a power of two (ops.py pads with zero wire rows); nb is
+    padded here to a multiple of ``ROWS`` with zero blocks."""
     N, nb, C = q.shape
     levels = int(math.log2(N))
     if 1 << levels != N:
         raise ValueError(f"N={N} not a power of two")
+    pb = (-nb) % ROWS
+    if pb:
+        q = jnp.pad(q, ((0, 0), (0, pb), (0, 0)))
+        scale = jnp.pad(scale, ((0, 0), (0, pb), (0, 0)))
     kernel = functools.partial(_int8_tree_reduce_kernel, levels=levels)
     out = pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((N, 1, C), lambda j: (0, j, 0)),
-                  pl.BlockSpec((N, 1, 1), lambda j: (0, j, 0))],
-        out_specs=pl.BlockSpec((1, C), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((1, nb * C), out_dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        grid=((nb + pb) // ROWS,),
+        in_specs=[pl.BlockSpec((N, ROWS, C), lambda j: (0, j, 0)),
+                  pl.BlockSpec((N, ROWS, 1), lambda j: (0, j, 0))],
+        out_specs=pl.BlockSpec((ROWS, C), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb + pb, C), out_dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, scale)
-    return out[0]
+    return out[:nb].reshape(-1)
 
 
 def _decode_add_bf16_kernel(k_ref, w_ref, o_ref):
@@ -130,7 +139,7 @@ def decode_add_bf16_pallas(keep: jax.Array, wire: jax.Array, *,
                   pl.BlockSpec((1, block), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, block), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, M), keep.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(keep[None], wire[None])
@@ -140,18 +149,25 @@ def decode_add_bf16_pallas(keep: jax.Array, wire: jax.Array, *,
 def decode_add_int8_pallas(keep: jax.Array, q: jax.Array, scale: jax.Array,
                            *, interpret: bool = False) -> jax.Array:
     """keep [M] + int8 wire (q [M/128, 128], scale [M/128, 1]) → [M]:
-    per-block dequant fused into the accumulate, one launch."""
+    per-block dequant fused into the accumulate, one launch.  The codec
+    blocks are tiled ``ROWS`` at a time (nb padded with zero blocks)."""
     nb, C = q.shape
+    keep = keep.reshape(nb, C)
+    pb = (-nb) % ROWS
+    if pb:
+        keep = jnp.pad(keep, ((0, pb), (0, 0)))
+        q = jnp.pad(q, ((0, pb), (0, 0)))
+        scale = jnp.pad(scale, ((0, pb), (0, 0)))
     out = pl.pallas_call(
         _decode_add_int8_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, C), lambda j: (j, 0)),
-                  pl.BlockSpec((1, C), lambda j: (j, 0)),
-                  pl.BlockSpec((1, 1), lambda j: (j, 0))],
-        out_specs=pl.BlockSpec((1, C), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, C), keep.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        grid=((nb + pb) // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, C), lambda j: (j, 0)),
+                  pl.BlockSpec((ROWS, C), lambda j: (j, 0)),
+                  pl.BlockSpec((ROWS, 1), lambda j: (j, 0))],
+        out_specs=pl.BlockSpec((ROWS, C), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((nb + pb, C), keep.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(keep.reshape(nb, C), q, scale)
-    return out.reshape(-1)
+    )(keep, q, scale)
+    return out[:nb].reshape(-1)

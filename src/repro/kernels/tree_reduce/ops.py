@@ -1,4 +1,4 @@
-"""Public tree-reduce ops: padding + interpret fallback + fused codecs.
+"""Public tree-reduce ops: padding + interpret mode off-TPU + fused codecs.
 
 Besides the plain ``tree_reduce``, this module owns the *codec-fused*
 variants that collapse the wire-codec dequantize into the reduction /
@@ -13,7 +13,7 @@ accumulate launch:
     (``core/collectives._codec_exchange_add``).
 
 Fusing drops one kernel launch per codec use, which is exactly the
-per-step α overhead ``core/autotune.CODEC_STEP_ALPHAS_FUSED`` prices —
+per-step α overhead ``core/autotune.CODEC_STEP_ALPHAS`` prices —
 the calibrated bucket tuner picks the cheaper codecs up automatically.
 
 Off-TPU, ``decode_add`` is EXACTLY the jnp expression
@@ -28,26 +28,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import import_pallas_kernels, on_tpu as _on_tpu
+from repro.kernels import on_tpu
 
+from .kernel import (decode_add_bf16_pallas, decode_add_int8_pallas,
+                     int8_tree_reduce_pallas, tree_reduce_pallas)
 from .ref import tree_reduce_ref
-
-(tree_reduce_pallas, int8_tree_reduce_pallas, decode_add_bf16_pallas,
- decode_add_int8_pallas, _PALLAS_OK) = import_pallas_kernels(
-    "repro.kernels.tree_reduce.kernel",
-    "tree_reduce_pallas", "int8_tree_reduce_pallas",
-    "decode_add_bf16_pallas", "decode_add_int8_pallas")
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def tree_reduce(x: jax.Array, *, block: int = 512,
                 interpret: bool | None = None) -> jax.Array:
     """[N, D] → [D] deterministic pairwise-tree sum. N padded up to a power
-    of two with zeros; D padded to the block size.  The reference fallback
-    keeps the same H-tree reduction order (bitwise determinism holds)."""
-    if not _PALLAS_OK:
-        return tree_reduce_ref(x)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    of two with zeros; D padded to the block size."""
+    interpret = (not on_tpu()) if interpret is None else interpret
     N, D = x.shape
     n2 = 1 << max(1, (N - 1).bit_length())
     block = min(block, 1 << (D - 1).bit_length() if D else block)
@@ -88,14 +81,6 @@ def encode_rows(x: jax.Array, codec: str):
     raise ValueError(f"unknown codec {codec!r}")
 
 
-def _decode_rows(wire, codec: str, dtype):
-    if codec in ("none", "bf16"):
-        return wire["x"].astype(dtype)
-    q, scale = wire["q"], wire["scale"]
-    x = q.astype(dtype) * scale.astype(dtype)
-    return x.reshape(q.shape[0], -1)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("codec", "block", "interpret"))
 def coded_tree_reduce(wire, codec: str, *, block: int = 512,
@@ -107,9 +92,7 @@ def coded_tree_reduce(wire, codec: str, *, block: int = 512,
     differ from decode-then-``tree_reduce`` by an ulp where the dequant
     multiply fuses into the first add.
     """
-    if not _PALLAS_OK:
-        return tree_reduce_ref(_decode_rows(wire, codec, jnp.float32))
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = (not on_tpu()) if interpret is None else interpret
     if codec == "int8":
         q, scale = wire["q"], wire["scale"]
         N = q.shape[0]
@@ -131,31 +114,38 @@ def coded_tree_reduce(wire, codec: str, *, block: int = 512,
 
 def decode_add(keep: jax.Array, wire, codec, *,
                interpret: bool | None = None) -> jax.Array:
-    """``keep + codec.decode(wire)`` as ONE launch when the Pallas path is
-    live — the fused receive+accumulate of a fractal halving exchange.
+    """``keep + codec.decode(wire)`` as ONE launch — the fused
+    receive+accumulate of a fractal halving exchange.
 
     ``codec`` is an ``optim.compression.Codec`` instance (its ``name``
-    selects the kernel; its ``decode`` is the fallback).  Off-TPU with
-    ``interpret=None`` this is EXACTLY ``keep + codec.decode(wire)`` —
-    bit-stable for the collective identity tests.  Flat f32/[M] payloads
-    only on the fused path; anything else falls back.
+    selects the kernel).  On TPU the kernel always runs: bf16 payloads of
+    any length are padded to the kernel block, int8 payloads must be flat
+    (q [M/128, 128]).  Off-TPU with ``interpret=None`` this is EXACTLY
+    ``keep + codec.decode(wire)`` — bit-stable for the collective identity
+    tests; ``interpret=True`` runs the kernel for parity tests.
     """
-    fused = _PALLAS_OK and (interpret if interpret is not None
-                            else _on_tpu())
-    if fused and keep.ndim == 1:
-        interpret = (not _on_tpu()) if interpret is None else interpret
-        M = keep.shape[0]
-        if codec.name == "bf16" and wire["x"].shape == (M,):
-            block = min(512, 1 << max(1, (M - 1).bit_length()))
-            if M % block == 0:
-                return decode_add_bf16_pallas(keep, wire["x"], block=block,
-                                              interpret=interpret)
-        if codec.name == "int8" and wire["q"].ndim == 2 \
-                and wire["q"].shape[0] * wire["q"].shape[1] == M:
-            return decode_add_int8_pallas(keep, wire["q"],
-                                          wire["scale"].reshape(-1, 1),
-                                          interpret=interpret)
-    return keep + codec.decode(wire, keep.shape, keep.dtype)
+    if interpret is None:
+        if not on_tpu():
+            return keep + codec.decode(wire, keep.shape, keep.dtype)
+        interpret = False
+    if codec.name == "bf16":
+        flat, x = keep.reshape(-1), wire["x"].reshape(-1)
+        M = flat.shape[0]
+        block = min(512, 1 << max(1, (M - 1).bit_length()))
+        pm = (-M) % block
+        out = decode_add_bf16_pallas(jnp.pad(flat, (0, pm)),
+                                     jnp.pad(x, (0, pm)), block=block,
+                                     interpret=interpret)
+        return out[:M].reshape(keep.shape)
+    if codec.name == "int8":
+        q = wire["q"]
+        if keep.ndim != 1 or q.ndim != 2 or q.size != keep.shape[0]:
+            raise ValueError(
+                f"fused int8 decode_add needs a flat payload: keep "
+                f"{keep.shape}, q {q.shape}")
+        return decode_add_int8_pallas(keep, q, wire["scale"].reshape(-1, 1),
+                                      interpret=interpret)
+    raise ValueError(f"no fused decode_add for codec {codec.name!r}")
 
 
 __all__ = ["tree_reduce", "tree_reduce_ref", "encode_rows",

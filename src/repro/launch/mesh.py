@@ -6,12 +6,10 @@ touches jax device state — the dry-run must set XLA_FLAGS before first init.
 
 from __future__ import annotations
 
-import jax
-
-from repro.compat import AxisType, make_mesh as _compat_make_mesh
-
-
 import math
+
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,15 +28,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(jax.devices())} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (launch/dryrun.py does this)")
-    return _compat_make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes),
-                             devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def make_mesh(shape, axes, devices=None):
-    return _compat_make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes),
-                             devices=devices)
+    """A mesh whose axes are all ``Auto`` (GSPMD-partitioned)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(tuple(axes)),
+                         devices=devices)
 
 
 def describe(mesh) -> str:

@@ -97,12 +97,14 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models import transformer as T
     from repro.models.registry import get_config
     from repro.serve import (EngineConfig, Request, ServeEngine,
                              parse_arrival_spec, serve_waves)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     params = T.init_params(cfg, jax.random.key(args.seed))
 
@@ -154,6 +156,7 @@ def main(argv=None):
              else "")
           + (f" devices={args.devices}" if args.devices else ""))
 
+    engine = None
     if args.mode == "wave":
         results, metrics = serve_waves(cfg, params, ecfg, requests)
     else:
@@ -167,7 +170,7 @@ def main(argv=None):
     print(metrics.report())
     shown = sorted(results)[:2]
     print("sample outputs:", [results[i][:8] for i in shown])
-    return results, metrics
+    return results, metrics, engine
 
 
 if __name__ == "__main__":

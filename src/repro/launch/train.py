@@ -27,14 +27,13 @@ superstep; ``--grad-accum K`` accumulates over K micro-batches per rank.
 
 import argparse
 import os
-import sys
 
 
 def _bucket_mb_arg(v):
     return "auto" if v == "auto" else float(v)
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
@@ -66,46 +65,55 @@ def main(argv=None):
     ap.add_argument("--checkpoint-every", type=int, default=50)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", ""))
 
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.models.registry import get_config
+
+    use_compile_cache()
+    return run(get_config(args.arch), args)
+
+
+def run(cfg, args: argparse.Namespace):
+    """Train ``cfg`` as ``args`` (from ``parse_args``) say, data-parallel
+    over every device JAX sees; returns the loop's result (``history``
+    holds each step's loss)."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.configs.base import ArchConfig
     from repro.core.bsp import BSPConfig
     from repro.data.pipeline import DataConfig, SyntheticLM
     from repro.launch.mesh import make_mesh
     from repro.models import transformer as T
-    from repro.models.registry import get_config
+    from repro.models.sharding import named
     from repro.optim import adamw
     from repro.runtime import trainer
     from repro.runtime.loop import LoopConfig, TrainLoop, resume_or_init
 
-    cfg = get_config(args.arch)
     n_dev = len(jax.devices())
     dp = n_dev
     mesh = make_mesh((dp, 1), ("data", "model"))
     acfg = adamw.AdamWConfig(lr=args.lr, total_steps=args.steps,
                              warmup_steps=max(1, args.steps // 10))
-
-    params = T.init_params(cfg, jax.random.key(args.seed))
-    print(f"arch={cfg.name} devices={n_dev} params="
-          f"{sum(x.size for x in jax.tree.leaves(params)):,}")
+    key = jax.random.key(args.seed)
 
     ckpt_meta = {}
     if args.schedule == "xla":
         step_fn, (pspec, ospec, bspec) = trainer.make_gspmd_train_step(
             cfg, mesh, acfg)
-        from repro.models.sharding import named
-        params = jax.device_put(params, named(mesh, pspec))
-        opt = adamw.init(params, acfg)
+        # params and moments are made in place, each device its own shard
+        params = jax.jit(lambda k: T.init_params(cfg, k),
+                         out_shardings=named(mesh, pspec))(key)
+        opt = jax.jit(lambda p: adamw.init(p, acfg),
+                      out_shardings=named(mesh, ospec))(params)
         state = (params, opt)
         bshard = {k: NamedSharding(mesh, s) for k, s in bspec.items()}
     else:
@@ -148,6 +156,9 @@ def main(argv=None):
                         link=link)
         step_fn, init_state = trainer.make_bsp_train_step(
             cfg, mesh, acfg, bsp, grad_accum=args.grad_accum)
+        # DP-replicated params, made on every device at once
+        params = jax.jit(lambda k: T.init_params(cfg, k),
+                         out_shardings=NamedSharding(mesh, P()))(key)
         state = init_state(params)
         ckpt_meta = {"superstep_layout": init_state.superstep_layout}
         bshard = {k: NamedSharding(mesh, P("data", *([None] * pad)))
@@ -156,6 +167,8 @@ def main(argv=None):
         if not cfg.frontend:
             bshard.pop("frontend")
 
+    print(f"arch={cfg.name} devices={n_dev} params="
+          f"{sum(x.size for x in jax.tree.leaves(state[0])):,}")
     state, start = resume_or_init(args.checkpoint_dir, state,
                                   expect_meta=ckpt_meta)
     data = SyntheticLM(cfg, DataConfig(global_batch=args.batch,

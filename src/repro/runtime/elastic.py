@@ -24,7 +24,6 @@ from typing import Iterable, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from repro.compat import HAS_AXIS_TYPE, AxisType
 from repro.core.tree import FractalTree
 from repro.runtime.fault_tolerance import surviving_domain
 
@@ -88,10 +87,9 @@ def build_mesh_from_tiles(tree: FractalTree, tiles: Sequence[Coord],
         raise ValueError(f"mesh_shape {mesh_shape} needs one entry per axis "
                          f"name {axis_names}")
     dev = np.array([devices[i] for i in flat_ids]).reshape(mesh_shape)
-    if HAS_AXIS_TYPE:
-        return jax.sharding.Mesh(dev, axis_names=axis_names,
-                                 axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.sharding.Mesh(dev, axis_names=axis_names)
+    return jax.sharding.Mesh(
+        dev, axis_names=axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def reshard_state(state, mesh, spec_tree):

@@ -443,15 +443,25 @@ def make_bsp_train_step(cfg: ArchConfig, mesh: Mesh, acfg: adamw.AdamWConfig,
     # only the genuinely-consumed moment shards
     step_fn = jax.jit(fn, donate_argnums=(1, 2))
 
-    def init_state(params) -> Tuple:
-        mu = jnp.zeros((flat_total,), jnp.float32)  # sharded by in_specs
+    # ZeRO-1 moments are made in place, each rank's shard on its own device
+    # (never the full length on one device)
+    shard = NamedSharding(mesh, shard_spec)
+    replicated = NamedSharding(mesh, P())
+
+    @partial(jax.jit, out_shardings=(
+        shard, shard, shard if has_codec else replicated, replicated))
+    def _zero_state():
+        mu = jnp.zeros((flat_total,), jnp.float32)
         nu = jnp.zeros((flat_total,), jnp.float32)
         # EF residual is PER-RANK state of full bucket-ordered length:
         # global (world × flat_total) sharded over the sync axes
         ef = jnp.zeros((world * flat_total,), jnp.float32) \
             if has_codec \
             else jnp.zeros((world,), jnp.float32)   # placeholder
-        return params, mu, nu, ef, jnp.zeros((), jnp.int32)
+        return mu, nu, ef, jnp.zeros((), jnp.int32)
+
+    def init_state(params) -> Tuple:
+        return (params,) + _zero_state()
 
     init_state.superstep_layout = layout_tag
     return step_fn, init_state

@@ -81,6 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import on_tpu
 from repro.models import transformer as T
 
 from .blocks import BlockAllocator, NoFreeBlocks
@@ -187,16 +188,12 @@ class ServeEngine:
         # "paged" only means something when there are positional leaves to
         # page: a pure-recurrent arch ignores the KV mode entirely
         self.paged = self.has_kv and kv_mode == "paged"
-        # "auto" takes the fused kernel only where it runs natively: on TPU
-        # with live Pallas dispatch.  Elsewhere it stays on the gather
-        # oracle (interpret-mode kernels would crawl); explicit "pallas"
-        # forces the kernel anywhere (interpret off-TPU) so parity tests
-        # can pin fused-vs-ref token identity on any host.
+        # "auto" follows the platform: the fused kernel on TPU, the gather
+        # oracle elsewhere (interpret-mode kernels would crawl); explicit
+        # "pallas" forces the kernel anywhere (interpret off-TPU) so parity
+        # tests can pin fused-vs-ref token identity on any host.
         if ecfg.paged_kernel == "auto":
-            from repro.compat import on_tpu
-            from repro.kernels import kernels_backend
-            self.paged_kernel = ("pallas" if on_tpu()
-                                 and kernels_backend() == "pallas" else "ref")
+            self.paged_kernel = "pallas" if on_tpu() else "ref"
         else:
             self.paged_kernel = ecfg.paged_kernel
         # a padded chunk must fit the cache row (a clamped dynamic-slice

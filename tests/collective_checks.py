@@ -18,7 +18,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import collectives as C  # noqa: E402
 from repro.core import schedule_ir as IR  # noqa: E402
 from repro.core.bsp import BSPConfig, bsp_shard_map, sync_gradients  # noqa: E402
@@ -34,9 +33,9 @@ def check(name, fn):
 
 
 def sm(fn, mesh, spec):
-    return jax.jit(compat.shard_map(fn, mesh, spec, spec,
-                                    check_vma=False,
-                                    axis_names=frozenset(mesh.axis_names)))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                                 out_specs=spec, check_vma=False,
+                                 axis_names=frozenset(mesh.axis_names)))
 
 
 def main():
@@ -121,8 +120,8 @@ def main():
         def do(schedule=schedule):
             cfg = BSPConfig(sync_axes=axes, schedule=schedule)
             f = lambda g: sync_gradients(g, cfg, sizes)
-            out = jax.jit(compat.shard_map(
-                f, mesh44, (gspec,), gspec,
+            out = jax.jit(jax.shard_map(
+                f, mesh=mesh44, in_specs=(gspec,), out_specs=gspec,
                 check_vma=False, axis_names=frozenset(("a", "b"))))(grads)
             w = np.asarray(out["w"]).reshape(n_dev, 1, 40, 3)
             b = np.asarray(out["b"]).reshape(n_dev, 5)
@@ -136,8 +135,8 @@ def main():
         def do(comp=comp, tol=tol):
             cfg = BSPConfig(sync_axes=axes, schedule="fractal", compression=comp)
             f = lambda g: sync_gradients(g, cfg, sizes)
-            out = jax.jit(compat.shard_map(
-                f, mesh44, (gspec,), gspec,
+            out = jax.jit(jax.shard_map(
+                f, mesh=mesh44, in_specs=(gspec,), out_specs=gspec,
                 check_vma=False, axis_names=frozenset(("a", "b"))))(grads)
             w = np.asarray(out["w"]).reshape(n_dev, 1, 40, 3)
             scale = np.abs(wmean).max()
@@ -178,14 +177,7 @@ def main():
         ref = (np.asarray(k) @ np.asarray(v)).reshape(4, 4, 8).sum(0)
         for d in range(4):
             np.testing.assert_allclose(got[d], ref, rtol=1e-4, atol=1e-4)
-    if compat.HAS_JAX_SHARD_MAP:
-        check("bsp_shard_map manual-DP + auto-model", auto_model)
-    else:
-        # jax 0.4.x SPMD cannot partition partial-auto shard_map bodies on
-        # host platforms (PartitionId unsupported); the all-manual paths
-        # above cover the schedules themselves.
-        print("skip bsp_shard_map manual-DP + auto-model "
-              "(legacy jax: partial-auto shard_map unsupported)", flush=True)
+    check("bsp_shard_map manual-DP + auto-model", auto_model)
 
     print(f"ALL OK ({len(PASS)} checks)")
 

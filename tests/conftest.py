@@ -1,13 +1,5 @@
 import pytest
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    # The tier-1 container has no hypothesis; run the property tests as a
-    # deterministic fixed-seed sweep instead of failing collection.
-    from _hypothesis_stub import install as _install_hypothesis_stub
-    _install_hypothesis_stub()
-
 
 def pytest_configure(config):
     config.addinivalue_line(

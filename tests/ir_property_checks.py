@@ -22,7 +22,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import collectives as C  # noqa: E402
 from repro.core import schedule_ir as IR  # noqa: E402
 
@@ -34,9 +33,9 @@ PASS = []
 
 def lower(prog, mesh, axes, x):
     spec = P(axes)
-    fn = compat.shard_map(lambda v: C.ir_all_reduce(v, prog, axes),
-                          mesh, spec, spec, check_vma=False,
-                          axis_names=frozenset(axes))
+    fn = jax.shard_map(lambda v: C.ir_all_reduce(v, prog, axes),
+                       mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False, axis_names=frozenset(axes))
     return jax.jit(fn)(x)
 
 

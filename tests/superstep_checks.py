@@ -24,7 +24,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import superstep as SS  # noqa: E402
 from repro.core.bsp import BSPConfig, sync_gradients  # noqa: E402
 
@@ -59,9 +58,10 @@ def ragged_tree(rng):
 
 def run_sync(tree, cfg):
     spec = jax.tree.map(lambda _: P(("a", "b")), tree)
-    fn = jax.jit(compat.shard_map(
-        lambda g: sync_gradients(g, cfg, SIZES), jax.make_mesh(SIZES, AXES),
-        (spec,), spec, check_vma=False, axis_names=frozenset(AXES)))
+    fn = jax.jit(jax.shard_map(
+        lambda g: sync_gradients(g, cfg, SIZES),
+        mesh=jax.make_mesh(SIZES, AXES), in_specs=(spec,), out_specs=spec,
+        check_vma=False, axis_names=frozenset(AXES)))
     return fn(tree)
 
 
@@ -204,10 +204,10 @@ def main():
         mesh = jax.make_mesh(SIZES, AXES)
 
         def run_rs(codec):
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda v: C.reduce_scatter(v, "fractal", AXES, SIZES,
                                            codec=codec),
-                mesh, (spec,), spec, check_vma=False,
+                mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False,
                 axis_names=frozenset(AXES)))
             return np.asarray(fn(flat))
 

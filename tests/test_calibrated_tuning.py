@@ -365,7 +365,7 @@ def test_zero1_codec_overhead_halved():
     wire = CM.program_cost_banded(prog, 1e7 * 0.5, link,
                                   mesh_contention=True)
     want = 0.5 * full + 0.5 * wire + \
-        0.5 * autotune.codec_step_alphas()["bf16"] * link.alpha_s \
+        0.5 * autotune.CODEC_STEP_ALPHAS["bf16"] * link.alpha_s \
         * prog.num_steps
     assert pols["bf16"] == pytest.approx(want)
 

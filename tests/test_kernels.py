@@ -6,15 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import kernels_backend
-
-# When the installed jax's Pallas lacks the API the kernels need, the ops
-# transparently dispatch to the pure-jnp references — comparing reference
-# against reference proves nothing, so skip instead of 20+ hard failures.
-pytestmark = pytest.mark.skipif(
-    kernels_backend() != "pallas",
-    reason="Pallas API unsupported by installed jax (ops fall back to ref)")
-
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.gemm.ops import gemm
@@ -293,12 +284,15 @@ def test_coded_tree_reduce_parity(codec, n, d):
 
 
 @pytest.mark.parametrize("codec", ["bf16", "int8"])
-def test_decode_add_fused_matches_unfused(codec):
+@pytest.mark.parametrize("m", [1024, 640])
+def test_decode_add_fused_matches_unfused(codec, m):
     """The fused receive-side accumulate == keep + codec.decode(wire), and
-    with default dispatch (off-TPU) it IS that expression bit for bit."""
+    with default dispatch (off-TPU) it IS that expression bit for bit.
+    m=640 is neither a multiple of the bf16 block nor of the int8 row tile,
+    so the kernels' padding is exercised too."""
     rng = np.random.default_rng(11)
-    keep = jnp.asarray(rng.normal(size=(1024,)), dtype=jnp.float32)
-    send = jnp.asarray(rng.normal(size=(1024,)), dtype=jnp.float32)
+    keep = jnp.asarray(rng.normal(size=(m,)), dtype=jnp.float32)
+    send = jnp.asarray(rng.normal(size=(m,)), dtype=jnp.float32)
     c = CODECS[codec]
     wire = c.encode(send)
     plain = keep + c.decode(wire, keep.shape, keep.dtype)

@@ -12,8 +12,7 @@ against a shadow model of table→block references.  After every op:
   * freed blocks are reusable: draining every table returns the pool to
     full capacity.
 
-Uses real ``hypothesis`` when installed (requirements-dev.txt); the
-deterministic fixed-seed stub otherwise (see ``tests/_hypothesis_stub.py``).
+Needs ``hypothesis`` (requirements-dev.txt).
 """
 
 from collections import Counter

@@ -10,7 +10,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models import ssm
 from repro.models.registry import get_config
 from repro.models import sharding as SH
-from repro.compat import abstract_mesh
 from repro.launch.mesh import make_mesh
 
 
@@ -71,7 +70,7 @@ def test_chunked_scan_tuple_carry_and_xs():
 def _specs_for(arch, mode, mesh_shape=(4, 4), axes=("data", "model")):
     cfg = get_config(arch)
     # AbstractMesh: the policy only reads axis sizes — no devices needed
-    mesh = abstract_mesh(mesh_shape, axes)
+    mesh = jax.sharding.AbstractMesh(tuple(mesh_shape), tuple(axes))
     from repro.models import transformer as T
     pshape = jax.eval_shape(lambda k: T.init_params(cfg, k),
                             jax.random.key(0))
@@ -141,7 +140,7 @@ def test_serve_mode_expert_sharding_covers_all_axes_when_divisible():
 
 def test_cache_specs_batch1_unsharded():
     cfg = get_config("jamba-v0.1-52b")
-    mesh = abstract_mesh((4, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((4, 4), ("data", "model"))
     from repro.models import transformer as T
     cshape = jax.eval_shape(lambda: T.init_cache(cfg, 1, 256))
     specs = SH.cache_specs(cfg, cshape, mesh)
