@@ -12,7 +12,6 @@ benches.  Prints ``name,us_per_call,derived`` CSV rows.
   serve_bench  continuous-batching engine vs wave baseline on ragged output
              lengths; asserts the occupancy + tokens/step win
   probes     XLA cost_analysis while-loop probe (motivates hlo_analysis)
-  roofline   per-(arch×shape×mesh) roofline table from results/dryrun/*.json
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only NAME]
 """
@@ -29,7 +28,7 @@ if "XLA_FLAGS" not in os.environ or "device_count" not in os.environ.get(
                                + os.environ.get("XLA_FLAGS", ""))
 
 BENCHES = ("table1", "area", "scaling", "schedules", "schedule_matrix",
-           "overlap", "serve_bench", "probes", "roofline")
+           "overlap", "serve_bench", "probes")
 
 
 def main(argv=None) -> None:

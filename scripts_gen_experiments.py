@@ -186,48 +186,6 @@ def sec_dryrun(single, multi):
     return "\n".join(out)
 
 
-def sec_roofline(single, multi):
-    sys.path.insert(0, str(ROOT / "benchmarks"))
-    import importlib
-    roofline = importlib.import_module("benchmarks.roofline")
-    out = ["## §Roofline — per (arch × shape × mesh), from the compiled HLO",
-           "",
-           "Terms per device per step (v5e: 197 bf16 TFLOP/s, 819 GB/s HBM, "
-           "50 GB/s/link ICI, 25 GB/s DCN inter-pod): compute = "
-           "HLO_FLOPs/peak; memory = HLO bytes/HBM-bw; collective = parsed "
-           "wire bytes/link-bw, split ici/dcn by replica-group pod "
-           "membership. **HLO_FLOPs/bytes are trip-count corrected** — "
-           "XLA's cost_analysis counts While bodies once "
-           "(benchmarks/probes.py), so a scanned 61-layer model "
-           "under-reports ~61×; `launch/hlo_analysis.py` rebuilds the "
-           "multipliers from `known_trip_count`. `useful FLOPs ratio` = "
-           "MODEL_FLOPS/HLO_FLOPs with MODEL_FLOPS = 6·N_active·tokens "
-           "(train) / 2·N_active·tokens (serve); `roofline frac` = "
-           "(MODEL_FLOPS/peak)/max-term — the MFU-style score.",
-           "",
-           roofline.markdown_table(), "",
-           "### Reading the table",
-           ""]
-    doms = {}
-    for recs in (single, multi):
-        for (arch, shape, tag), r in recs.items():
-            if tag or r.get("status") != "ok":
-                continue
-            d = r.get("roofline", {}).get("dominant", "?")
-            doms.setdefault(d, []).append((arch, shape, r["mesh"]))
-    for d, cells in sorted(doms.items()):
-        out.append(f"- **{d.replace('_s','')}-bound** ({len(cells)} cells): "
-                   f"move it down by: {MOVE_DOWN.get(d, '—')}.")
-    out += ["",
-            "Decode cells are memory/collective-bound (every step reads "
-            "params + cache: arithmetic intensity ≈ 1-2 flops/byte ⇒ "
-            "roofline fraction is inherently ~bandwidth-limited at "
-            "batch≤128); train cells are memory-bound in this baseline "
-            "because the blocked-attention HLO round-trips scores through "
-            "HBM — the §Perf log drives exactly that term down."]
-    return "\n".join(out)
-
-
 def sec_perf(single, multi):
     out = ["## §Perf — hypothesis → change → measure → validate",
            "",
@@ -291,7 +249,7 @@ def main():
            "commands in DESIGN.md §8.",
            "",
            sec_table1(), "", sec_area(), "", sec_schedules(), "",
-           sec_dryrun(single, multi), "", sec_roofline(single, multi), "",
+           sec_dryrun(single, multi), "",
            sec_perf(single, multi), ""]
     extra = ROOT / "EXPERIMENTS_extra.md"
     if extra.exists():

@@ -125,6 +125,7 @@ def paged_attention_pallas(q, k_pool, v_pool, tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, dv), q.dtype),
+        name="paged_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
@@ -211,6 +212,7 @@ def paged_mla_attention_pallas(q_eff, q_rope, ckv_pool, kr_pool, tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, r), q_eff.dtype),
+        name="paged_mla_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,

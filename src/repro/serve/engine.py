@@ -65,6 +65,14 @@ inside one engine run:
     for recurrent-bearing archs: a prefix hit would skip the state
     computation the recurrence needs.
 
+Tracing: the engine names its own phases, always on (with no profiler
+running, a span is a couple of Python calls).  ``serve.step`` (a step
+annotation) holds ``serve.admit``, one ``serve.prefill`` per chunk
+(``req_id=``, ``chunk=``; children ``.prepare``, ``.dispatch``,
+``.first_token``, ``.publish``) and
+``serve.decode.{prepare,dispatch,readback,commit}``; under a profiler they
+land on the host's trace beside the device's ``jit_serve_*`` programs.
+
 ``serve_waves`` keeps the old wave-at-a-time loop alive as the TEST ORACLE
 (plus the measured baseline for ``benchmarks/serve_bench.py``): it batch-
 prefills whole prompts with no chunking, no masking and no slot reuse, so
@@ -79,6 +87,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.kernels import on_tpu
@@ -133,6 +142,13 @@ def _check_arch(cfg: ArchConfig) -> None:
         raise ValueError(
             f"{cfg.name}: frontend architectures are not servable "
             "(requests are token-only)")
+
+
+def _named_jit(fn, name: str):
+    """``jax.jit(fn)`` under a stable program name: ``jit_<name>`` in a
+    profiler trace and in the compiled module."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 def _make_sampler(base_key, temperature: float):
@@ -229,6 +245,8 @@ class ServeEngine:
         self.results: Dict[int, List[int]] = {}
         self._key = jax.random.key(ecfg.seed)
         self._admission_hold = 0     # steps left with admission stalled
+        self._steps = 0              # engine steps taken (trace step_num)
+        self._step_t0 = 0.0          # serve-clock start of the current step
 
         self._data_spec = None
         if mesh is not None:
@@ -287,10 +305,10 @@ class ServeEngine:
         # backend inputs are passed as None (an empty pytree — traced away)
         pk = self.paged_kernel
         contig_kv = self.has_kv and not self.paged
-        self._decode = jax.jit(
+        self._decode = _named_jit(
             lambda p, tok, c, off, bt, rows, act: T.decode_step(
                 p, cfg, tok, c, off, block_tables=bt, paged_kernel=pk,
-                rec_rows=rows, active=act))
+                rec_rows=rows, active=act), "serve_decode")
 
         # admission: contiguous KV slices the slot's row, prefills one
         # chunk into it, writes it back (paged mode addresses the pool
@@ -298,7 +316,7 @@ class ServeEngine:
         # is row-addressed in place via ``rec_row``).  Interior chunks only
         # feed the cache, so they skip the full-vocab head projection (the
         # dominant admission FLOPs at real vocab sizes)
-        def admit(with_logits):
+        def admit(with_logits, name):
             def fn(p, c, tokens, slot, offset, table, rec_row, valid):
                 sub = T.take_state(cfg, c, slot) if contig_kv else c
                 logits, sub = T.prefill_chunk(
@@ -307,15 +325,19 @@ class ServeEngine:
                 if contig_kv:
                     return logits, T.write_state(cfg, c, sub, slot)
                 return logits, sub
-            return jax.jit(fn)
-        self._admit = admit(True)
-        self._admit_quiet = admit(False)
-        self._reset = jax.jit(
+            return _named_jit(fn, name)
+        self._admit = admit(True, "serve_prefill")
+        self._admit_quiet = admit(False, "serve_prefill_quiet")
+        self._reset = _named_jit(
             lambda c, slot, row: T.reset_slot_state(cfg, c, slot=slot,
-                                                    rec_row=row))
+                                                    rec_row=row),
+            "serve_reset")
         if self.paged:
-            self._copy = jax.jit(T.copy_block)
-        self._sample = jax.jit(_make_sampler(self._key, ecfg.temperature))
+            self._copy = _named_jit(
+                lambda c, src, dst: T.copy_block(c, src, dst),
+                "serve_copy_block")
+        self._sample = _named_jit(
+            _make_sampler(self._key, ecfg.temperature), "serve_sample")
 
     def _put(self, x):
         if self._data_spec is None:
@@ -465,39 +487,40 @@ class ServeEngine:
 
     # -- engine phases (one call each per step) ---------------------------
     def _admit_ready(self, now_s: float) -> None:
-        for slot in self.table.free():
-            req = self.queue.pop_ready(now_s)
-            if req is None:
-                return
-            # TWO-RESOURCE admission: every backend must have capacity
-            # before either commits (nothing to unwind on failure).
-            # Recurrent rows never free mid-decode, so a deferral clears
-            # only when a request finishes (or is preempted); FIFO order
-            # is preserved by requeueing and admitting nobody behind the
-            # blocked request.
-            if self.rec is not None and self.rec.num_free == 0:
-                self.queue.submit(req)
-                return
-            if self.paged:
-                if not self._try_admit_paged(slot, req):
-                    # not enough free blocks: put the request back (the
-                    # queue re-sorts it into place) and keep FIFO order by
-                    # not admitting anyone behind it
+        with TraceAnnotation("serve.admit"):
+            for slot in self.table.free():
+                req = self.queue.pop_ready(now_s)
+                if req is None:
+                    return
+                # TWO-RESOURCE admission: every backend must have capacity
+                # before either commits (nothing to unwind on failure).
+                # Recurrent rows never free mid-decode, so a deferral clears
+                # only when a request finishes (or is preempted); FIFO order
+                # is preserved by requeueing and admitting nobody behind the
+                # blocked request.
+                if self.rec is not None and self.rec.num_free == 0:
                     self.queue.submit(req)
                     return
-            else:
-                self.table.assign(slot, req)
-                self.metrics.on_admit(req.req_id)
-            if self.rec is not None:
-                slot.rec_row = self.rec.alloc()
-            # device-side hygiene: a reused contiguous slot row and/or
-            # recurrent row starts zeroed (paged blocks need no reset —
-            # fresh blocks are written before they are ever read)
-            if self.rec is not None or not self.paged:
-                slot_idx = (slot.index if self.has_kv and not self.paged
-                            else None)
-                row = slot.rec_row if self.rec is not None else None
-                self.cache = self._reset(self.cache, slot_idx, row)
+                if self.paged:
+                    if not self._try_admit_paged(slot, req):
+                        # not enough free blocks: put the request back (the
+                        # queue re-sorts it into place) and keep FIFO order by
+                        # not admitting anyone behind it
+                        self.queue.submit(req)
+                        return
+                else:
+                    self.table.assign(slot, req)
+                    self.metrics.on_admit(req.req_id)
+                if self.rec is not None:
+                    slot.rec_row = self.rec.alloc()
+                # device-side hygiene: a reused contiguous slot row and/or
+                # recurrent row starts zeroed (paged blocks need no reset —
+                # fresh blocks are written before they are ever read)
+                if self.rec is not None or not self.paged:
+                    slot_idx = (slot.index if self.has_kv and not self.paged
+                                else None)
+                    row = slot.rec_row if self.rec is not None else None
+                    self.cache = self._reset(self.cache, slot_idx, row)
 
     def _finish(self, slot) -> None:
         req = slot.request
@@ -537,11 +560,29 @@ class ServeEngine:
         """
         C = self._chunk
         budget = self.ecfg.chunks_per_step
+        fed = set()
         for slot in self.table.prefilling():
             if budget <= 0:
-                return
+                break
             if slot.state != PREFILL:   # preempted earlier this tick
                 continue
+            with TraceAnnotation("serve.prefill", req_id=slot.req_id,
+                                 chunk=slot.prefill_pos // C):
+                if not self._prefill_chunk(slot):
+                    continue                    # preempted mid-COW
+            fed.add(slot.req_id)
+            budget -= 1
+        self.metrics.on_prefill_passed_over(
+            [s.req_id for s in self.table.prefilling()
+             if s.req_id not in fed], self._step_t0)
+
+    def _prefill_chunk(self, slot) -> bool:
+        """Run ``slot``'s next prompt chunk (geometry: ``_prefill_tick``);
+        on the prompt's last chunk, sample its first token.  Returns False
+        when the slot was preempted while its chunk's blocks were made
+        writable."""
+        C = self._chunk
+        with TraceAnnotation("serve.prefill.prepare"):
             prompt = np.asarray(slot.request.prompt, np.int32)
             plen = len(prompt)
             remaining = plen - slot.prefill_pos
@@ -566,37 +607,41 @@ class ServeEngine:
             admit = self._admit if final else self._admit_quiet
             if self.paged:
                 if not self._ensure_writable_range(slot, start, start + C):
-                    continue                    # preempted mid-COW
+                    return False
                 table = jnp.asarray(self.table.block_table_row(slot))
             else:
                 table = None
             rec_row = (None if self.rec is None
                        else jnp.asarray([slot.rec_row], jnp.int32))
-            logits, self.cache = admit(
-                self.params, self.cache, jnp.asarray(chunk), slot.index,
-                jnp.asarray(start, jnp.int32), table, rec_row,
-                None if valid is None else jnp.asarray(valid, jnp.int32))
-            slot.prefill_pos += min(remaining, C)
-            slot.length = slot.prefill_pos
-            self.metrics.on_prefill_chunk(min(remaining, C))
-            budget -= 1
-            if slot.prefill_pos >= plen:
-                # prompt fully cached: sample the request's token 0 from the
-                # logits at the REAL last prompt position of this chunk
-                row = jnp.asarray(logits)[:, last_row]          # [1,V]
-                tok = int(self._sample(
-                    row, jnp.asarray([slot.req_id], jnp.int32),
-                    jnp.asarray([0], jnp.int32))[0])
-                self.table.activate(slot, tok)
-                if self.paged and not self.has_rec:
-                    # publish the full prompt blocks so identical prompts
-                    # admitted later share them (first writer wins);
-                    # recurrent archs never share — see _try_admit_paged
-                    keys = self.allocator.prefix_keys(slot.request.prompt)
-                    for i, key in enumerate(keys):
-                        self.allocator.publish(slot.blocks[i], key)
-                self.metrics.on_first_token(slot.req_id)
-                self._complete_if_done(slot, tok)
+            inputs = (jnp.asarray(chunk), slot.index,
+                      jnp.asarray(start, jnp.int32), table, rec_row,
+                      None if valid is None else jnp.asarray(valid, jnp.int32))
+        with TraceAnnotation("serve.prefill.dispatch"):
+            logits, self.cache = admit(self.params, self.cache, *inputs)
+        slot.prefill_pos += min(remaining, C)
+        slot.length = slot.prefill_pos
+        self.metrics.on_prefill_chunk(min(remaining, C))
+        if slot.prefill_pos < plen:
+            return True
+        # prompt fully cached: sample the request's token 0 from the logits
+        # at the REAL last prompt position of this chunk
+        with TraceAnnotation("serve.prefill.first_token"):
+            row = jnp.asarray(logits)[:, last_row]              # [1,V]
+            tok = int(self._sample(
+                row, jnp.asarray([slot.req_id], jnp.int32),
+                jnp.asarray([0], jnp.int32))[0])
+        self.table.activate(slot, tok)
+        if self.paged and not self.has_rec:
+            # publish the full prompt blocks so identical prompts admitted
+            # later share them (first writer wins); recurrent archs never
+            # share — see _try_admit_paged
+            with TraceAnnotation("serve.prefill.publish"):
+                keys = self.allocator.prefix_keys(slot.request.prompt)
+                for i, key in enumerate(keys):
+                    self.allocator.publish(slot.blocks[i], key)
+        self.metrics.on_first_token(slot.req_id)
+        self._complete_if_done(slot, tok)
+        return True
 
     def _grow_decode_blocks(self) -> None:
         """Paged: every ACTIVE slot writes its pending token at position
@@ -618,32 +663,38 @@ class ServeEngine:
         self._record_blocks()
 
     def _decode_tick(self) -> None:
-        if self.paged:
-            self._grow_decode_blocks()
-        if self.table.n_active == 0:
-            return
-        tokens, offsets, active, req_ids, tok_idx = self.table.decode_inputs()
-        bt = rows = act = None
-        if self.paged:
-            bt = self._put(jnp.asarray(self.table.block_tables()))
-        if self.rec is not None:
-            rows = self._put(jnp.asarray(self.table.rec_rows()))
-            act = self._put(jnp.asarray(active))
-        logits, self.cache = self._decode(
-            self.params, self._put(jnp.asarray(tokens)), self.cache,
-            self._put(jnp.asarray(offsets)), bt, rows, act)
-        toks = np.asarray(self._sample(
-            logits[:, 0], self._put(jnp.asarray(req_ids)),
-            self._put(jnp.asarray(tok_idx))))
-        self.metrics.on_decode_step(int(active.sum()))
-        for slot in self.table.active():
-            tok = int(toks[slot.index])
-            slot.length += 1          # pending token was cached this step
-            slot.pending_token = tok
-            slot.generated += 1
-            slot.output.append(tok)
-            self.metrics.on_token(slot.req_id)
-            self._complete_if_done(slot, tok)
+        with TraceAnnotation("serve.decode.prepare"):
+            if self.paged:
+                self._grow_decode_blocks()
+            if self.table.n_active == 0:
+                return
+            tokens, offsets, active, req_ids, tok_idx = \
+                self.table.decode_inputs()
+            bt = rows = act = None
+            if self.paged:
+                bt = self._put(jnp.asarray(self.table.block_tables()))
+            if self.rec is not None:
+                rows = self._put(jnp.asarray(self.table.rec_rows()))
+                act = self._put(jnp.asarray(active))
+            tok_in = self._put(jnp.asarray(tokens))
+            off_in = self._put(jnp.asarray(offsets))
+        with TraceAnnotation("serve.decode.dispatch"):
+            logits, self.cache = self._decode(
+                self.params, tok_in, self.cache, off_in, bt, rows, act)
+        with TraceAnnotation("serve.decode.readback"):
+            toks = np.asarray(self._sample(
+                logits[:, 0], self._put(jnp.asarray(req_ids)),
+                self._put(jnp.asarray(tok_idx))))
+        with TraceAnnotation("serve.decode.commit"):
+            self.metrics.on_decode_step(int(active.sum()))
+            for slot in self.table.active():
+                tok = int(toks[slot.index])
+                slot.length += 1      # pending token was cached this step
+                slot.pending_token = tok
+                slot.generated += 1
+                slot.output.append(tok)
+                self.metrics.on_token(slot.req_id)
+                self._complete_if_done(slot, tok)
 
     def hold_admission(self, steps: int) -> None:
         """Stall admission for the next ``steps`` engine steps (fault
@@ -658,14 +709,17 @@ class ServeEngine:
     def step(self) -> None:
         """One engine iteration: admissions, a prefill tick, a decode step,
         and a clock tick (virtual mode — wall time passes on its own)."""
-        if self._admission_hold > 0:
-            self._admission_hold -= 1
-        else:
-            self._admit_ready(self.metrics.now())
-        self._prefill_tick()
-        self._decode_tick()
-        self.metrics.on_queue_depth(len(self.queue))
-        self.metrics.tick()
+        with StepTraceAnnotation("serve.step", step_num=self._steps):
+            self._steps += 1
+            self._step_t0 = self.metrics.now()
+            if self._admission_hold > 0:
+                self._admission_hold -= 1
+            else:
+                self._admit_ready(self._step_t0)
+            self._prefill_tick()
+            self._decode_tick()
+            self.metrics.on_queue_depth(len(self.queue))
+            self.metrics.tick()
 
     def run(self, requests: Optional[Sequence[Request]] = None
             ) -> Dict[int, List[int]]:

@@ -103,6 +103,10 @@ class RequestRecord:
     finished_s: Optional[float] = None
     prompt_len: int = 0
     tokens_out: int = 0
+    # serve-clock seconds of the engine steps in which the request held a
+    # prefilling slot but another slot's chunk ran: TTFT = queue wait
+    # (admitted - arrival) + this + the steps of its own chunks
+    prefill_wait_s: float = 0.0
 
     @property
     def ttft_s(self) -> Optional[float]:
@@ -140,6 +144,9 @@ class ServeMetrics:
     step_s: float = 0.01             # virtual seconds per engine step
     _t0: Optional[float] = None
     _vt: float = 0.0                 # virtual clock position (step mode)
+    # (request ids, step start) passed over by the current step's prefill
+    # tick: their prefill wait grows by the step's duration at tick()
+    _passed_over: Optional[Tuple[List[int], float]] = None
     wall_s: float = 0.0
     # streaming percentile estimators (P², O(1) memory): always on — a
     # preempted-and-reserved request contributes BOTH its ttft samples
@@ -165,6 +172,12 @@ class ServeMetrics:
         real time passed on its own)."""
         if self.clock == "step":
             self._vt += self.step_s
+        if self._passed_over is not None:
+            req_ids, since = self._passed_over
+            self._passed_over = None
+            dt = self.now() - since
+            for rid in req_ids:
+                self.requests[rid].prefill_wait_s += dt
 
     def wait_until(self, t: float) -> None:
         """Idle until the serve clock reaches ``t``: the virtual clock
@@ -190,6 +203,14 @@ class ServeMetrics:
     def on_prefill_chunk(self, n_tokens: int) -> None:
         self.prefill_chunks += 1
         self.prefill_tokens += n_tokens
+
+    def on_prefill_passed_over(self, req_ids: List[int],
+                               step_start_s: float) -> None:
+        """The requests ``req_ids`` hold prefilling slots that got no chunk
+        in the engine step that began at ``step_start_s``: each one's
+        ``prefill_wait_s`` grows by the step's duration when it ends
+        (``tick``)."""
+        self._passed_over = (req_ids, step_start_s) if req_ids else None
 
     def on_first_token(self, req_id: int) -> None:
         r = self.requests[req_id]
@@ -254,6 +275,9 @@ class ServeMetrics:
         r.first_token_s = None
         r.finished_s = None
         r.tokens_out = 0
+        r.prefill_wait_s = 0.0
+        if self._passed_over is not None and req_id in self._passed_over[0]:
+            self._passed_over[0].remove(req_id)
 
     # -- aggregates -------------------------------------------------------
     @property

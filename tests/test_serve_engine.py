@@ -418,3 +418,69 @@ def test_wave_and_continuous_token_identical_greedy(cfg, params):
     cont = ServeEngine(cfg, params, ecfg).run(_requests(cfg, lens, gens))
     wave, _ = serve_waves(cfg, params, ecfg, _requests(cfg, lens, gens))
     assert cont == wave
+
+
+# ---------------------------------------------------------------------------
+# prefill wait: TTFT = queue wait + prefill wait + the request's own chunks
+# ---------------------------------------------------------------------------
+
+
+def _ttft_parts_hold(eng, lens):
+    """On the step clock, with one chunk a step, every step from a
+    request's admission to its first token either runs one of its chunks
+    or passes it over; the first token comes in its last chunk's step."""
+    C, step = eng.ecfg.prefill_chunk, eng.ecfg.step_s
+    for i, n in enumerate(lens):
+        r = eng.metrics.requests[i]
+        own = -(-n // C)
+        assert r.ttft_s == pytest.approx(
+            (r.admitted_s - r.arrival_s) + r.prefill_wait_s
+            + (own - 1) * step), i
+
+
+@pytest.mark.parametrize("kv_mode", ["contiguous", "paged"])
+def test_prefill_wait_is_the_neighbours_chunks(cfg, params, kv_mode):
+    """Two requests admitted in the same step, one chunk a step: the
+    later slot waits exactly its neighbour's chunk count times
+    ``step_s``; the earlier one never waits."""
+    lens = [12, 8]                                   # 3 and 2 chunks of 4
+    eng = ServeEngine(cfg, params, _ecfg(chunks_per_step=1, kv_mode=kv_mode,
+                                         block_size=4))
+    eng.run(_requests(cfg, lens, [2, 2]))
+    recs = eng.metrics.requests
+    assert recs[0].admitted_s == recs[1].admitted_s == 0.0
+    assert recs[0].prefill_wait_s == 0.0
+    assert recs[1].prefill_wait_s == pytest.approx(3 * eng.ecfg.step_s)
+    _ttft_parts_hold(eng, lens)
+
+
+def test_ttft_splits_into_queue_wait_prefill_wait_and_own_chunks(cfg,
+                                                                 params):
+    lens, gens = [12, 8, 5, 16, 9], [3, 2, 4, 2, 3]
+    reqs = _requests(cfg, lens, gens, seed=2,
+                     arrivals=[0.0, 0.0, 0.01, 0.02, 0.05])
+    eng = ServeEngine(cfg, params, _ecfg(chunks_per_step=1))
+    eng.run(reqs)
+    assert any(r.prefill_wait_s > 0 for r in eng.metrics.requests.values())
+    assert any(r.admitted_s > r.arrival_s
+               for r in eng.metrics.requests.values())
+    _ttft_parts_hold(eng, lens)
+
+
+def test_preemption_resets_prefill_wait():
+    from repro.serve.metrics import ServeMetrics
+    m = ServeMetrics(clock="step", step_s=0.5)
+    m.on_submit(7, 0.0, 10)
+    m.on_admit(7)
+    m.on_prefill_passed_over([7], m.now())
+    m.tick()
+    assert m.requests[7].prefill_wait_s == 0.5
+    m.on_preempt(7)
+    assert m.requests[7].prefill_wait_s == 0.0
+    # passed over, then preempted later in the same step: the step that
+    # ends after the preemption is not booked to the requeued request
+    m.on_admit(7)
+    m.on_prefill_passed_over([7], m.now())
+    m.on_preempt(7)
+    m.tick()
+    assert m.requests[7].prefill_wait_s == 0.0
