@@ -1,4 +1,4 @@
-"""Public fused paged-attention decode ops: GQA grouping + dispatch.
+"""Public fused paged-attention decode ops: decode-query checks + dispatch.
 
 Decode-only (T == 1), forward-only (no grads flow at serve time), so no
 custom_vjp is needed.  The kernel runs natively on TPU and in interpret
@@ -43,16 +43,13 @@ def paged_attention(q, k_pool, v_pool, tables, offset, *, scale=None,
     B, T, Hq, d = q.shape
     if T != 1:
         raise ValueError(f"paged_attention is decode-only (T==1), got T={T}")
-    Hkv = k_pool.shape[2]
-    G = Hq // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    qh = q[:, 0].reshape(B, Hkv, G, d)
     lengths = _lengths(offset, B)
     interpret = (not on_tpu()) if interpret is None else interpret
-    o = paged_attention_pallas(qh, k_pool, v_pool, tables, lengths,
+    o = paged_attention_pallas(q[:, 0], k_pool, v_pool, tables, lengths,
                                scale=scale, window=window, softcap=softcap,
                                interpret=interpret)
-    return o.reshape(B, 1, Hq, v_pool.shape[-1])
+    return o[:, None]
 
 
 def paged_mla_attention(q_eff, q_rope, ckv_pool, kr_pool, tables, offset, *,

@@ -10,6 +10,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.gemm.ops import gemm
 from repro.kernels.gemm.ref import gemm_ref
+from repro.kernels.paged_attention import kernel as paged_kernel
 from repro.kernels.paged_attention.ops import (paged_attention,
                                                paged_mla_attention)
 from repro.kernels.paged_attention.ref import (paged_attention_ref,
@@ -206,6 +207,22 @@ def test_paged_attention_ignores_sentinel_and_unreferenced_blocks():
     vp2 = vp.at[jnp.asarray(poison)].set(1e9)
     out2 = paged_attention(q, kp2, vp2, tables, offset)
     assert np.array_equal(np.asarray(out), np.asarray(out2))
+    # table entries past each row's length name poisoned blocks too
+    out3 = paged_attention(q, kp2, vp2, _poison_tails(tables, offset,
+                                                     kp.shape[1], poison),
+                           offset)
+    assert np.array_equal(np.asarray(out), np.asarray(out3))
+
+
+def _poison_tails(tables, offset, bs, poison):
+    """``tables`` with every entry past each row's live pages replaced by
+    a (non-sentinel) poisoned block."""
+    t = np.asarray(tables).copy()
+    fill = [i for i in poison if i != 0]
+    for b in range(t.shape[0]):
+        live = -(-int(offset[b] + 1) // bs)
+        t[b, live:] = [fill[j % len(fill)] for j in range(t.shape[1] - live)]
+    return jnp.asarray(t)
 
 
 def test_paged_attention_invariant_to_block_placement():
@@ -230,6 +247,120 @@ def test_paged_attention_rejects_multi_token():
     q2 = jnp.concatenate([q, q], axis=1)
     with pytest.raises(ValueError, match="decode-only"):
         paged_attention(q2, kp, vp, tables, offset)
+
+
+# Rows whose live pages span several of the kernel's page groups: the group
+# is held to 3 pages (12 tokens), so a 40-page table is 14 groups, the last
+# one partial.  Rows: one token; ending on a page edge (5 pages); 4 pages,
+# straddling the first group edge; the whole table; and a masked row (all
+# sentinel, the engine's placeholder length), which must stream nothing.
+_MG_OFFSETS = (0, 19, 13, 159, 159)
+_MG_MASKED = 4
+
+
+def _multi_group_case(dtype, seed=0, n=40, N=120, bs=4, Hkv=2, G=3, d=16):
+    rng = np.random.default_rng(seed)
+    B = len(_MG_OFFSETS)
+    q = jnp.asarray(rng.normal(size=(B, 1, Hkv * G, d)), dtype=dtype)
+    kp = jnp.asarray(rng.normal(size=(N, bs, Hkv, d)), dtype=dtype)
+    vp = jnp.asarray(rng.normal(size=(N, bs, Hkv, d)), dtype=dtype)
+    tables = np.zeros((B, n), np.int32)
+    offset = np.asarray(_MG_OFFSETS, np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    for b in range(B):
+        if b != _MG_MASKED:
+            live = -(-int(offset[b] + 1) // bs)
+            tables[b, :live] = [free.pop() for _ in range(live)]
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(offset)
+
+
+@pytest.fixture
+def three_page_groups(monkeypatch):
+    """Hold the GQA kernel's group to 3 pages at the tiny test widths."""
+    def use(kp, vp):
+        page = kp.shape[1] * kp.shape[2] * (kp.shape[3] + vp.shape[3]) \
+            * kp.dtype.itemsize
+        monkeypatch.setattr(paged_kernel, "_GROUP_BYTES", 3 * page)
+    return use
+
+
+def _ref_rows(q, kp, vp, tables, offset, **kw):
+    B, _, Hq, d = q.shape
+    Hkv = kp.shape[2]
+    qh = q[:, 0].reshape(B, Hkv, Hq // Hkv, d)
+    return paged_attention_ref(qh, kp, vp, tables, offset + 1,
+                               scale=1.0 / np.sqrt(d),
+                               **kw).reshape(B, 1, Hq, -1)
+
+
+def test_pages_per_group_follows_page_bytes():
+    """~1 MiB of K+V pages a group, at least one, at most the table."""
+    phi4 = 16 * 8 * (128 + 128) * 2          # 64 KiB a page
+    qwen = 16 * 2 * (128 + 128) * 2          # 16 KiB a page
+    assert paged_kernel.pages_per_group(phi4, 256) == 16
+    assert paged_kernel.pages_per_group(qwen, 128) == 64
+    assert paged_kernel.pages_per_group(qwen, 40) == 40
+    assert paged_kernel.pages_per_group(4 << 20, 256) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window,softcap",
+                         [(None, None), (5, None), (None, 8.0), (6, 4.0)])
+def test_paged_attention_multi_group_parity(three_page_groups, dtype,
+                                            window, softcap):
+    q, kp, vp, tables, offset = _multi_group_case(dtype)
+    three_page_groups(kp, vp)
+    out = paged_attention(q, kp, vp, tables, offset, window=window,
+                          softcap=softcap)
+    ref = _ref_rows(q, kp, vp, tables, offset, window=window,
+                    softcap=softcap)
+    live = [b for b in range(q.shape[0]) if b != _MG_MASKED]
+    assert out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               **_tol(dtype))
+    # the masked row streams nothing and reads zeros, not NaN
+    assert not np.asarray(out, np.float32)[_MG_MASKED].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_masked_rows_leave_active_rows_bit_identical(
+        three_page_groups, dtype):
+    """Active rows read the same bits whether masked rows sit between
+    them (which change the order of the kernel's cross-row prefetches and
+    what the VMEM buffers held before) or not."""
+    q, kp, vp, tables, offset = _multi_group_case(dtype, seed=4)
+    three_page_groups(kp, vp)
+    out = np.asarray(paged_attention(q, kp, vp, tables, offset), np.float32)
+    live = [b for b in range(q.shape[0]) if b != _MG_MASKED]
+    idx = jnp.asarray(live)
+    alone = paged_attention(q[idx], kp, vp, tables[idx], offset[idx])
+    assert np.array_equal(out[live], np.asarray(alone, np.float32))
+    # masked rows first, between and last
+    order = [_MG_MASKED, live[0], _MG_MASKED, live[1], live[2], _MG_MASKED,
+             live[3], _MG_MASKED]
+    idx = jnp.asarray(order)
+    mixed = np.asarray(paged_attention(q[idx], kp, vp, tables[idx],
+                                       offset[idx]), np.float32)
+    got = [mixed[i] for i, b in enumerate(order) if b != _MG_MASKED]
+    assert np.array_equal(out[live], np.stack(got))
+
+
+def test_paged_attention_multi_group_ignores_blocks_past_length(
+        three_page_groups):
+    """Poisoned sentinel, unreferenced blocks and table entries past each
+    row's length, across page groups: not one bit moves."""
+    q, kp, vp, tables, offset = _multi_group_case(jnp.float32, seed=2)
+    three_page_groups(kp, vp)
+    out = paged_attention(q, kp, vp, tables, offset)
+    live = {int(t) for t in np.asarray(tables).ravel()} - {0}
+    poison = [i for i in range(kp.shape[0]) if i not in live]
+    kp2 = kp.at[jnp.asarray(poison)].set(1e9)
+    vp2 = vp.at[jnp.asarray(poison)].set(1e9)
+    out2 = paged_attention(q, kp2, vp2, _poison_tails(tables, offset,
+                                                      kp.shape[1], poison),
+                           offset)
+    assert np.array_equal(np.asarray(out), np.asarray(out2))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

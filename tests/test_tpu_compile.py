@@ -7,6 +7,9 @@ things interpret mode never sees.  Shapes are the real widths of the
 configurations the serve and train paths run.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,6 +57,10 @@ def _compiled_text(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+# one HLO instruction: its name, the dims of the array it produces, opcode
+_HLO_OP = re.compile(r"%(\S+) = \w+\[([\d,]+)\]\S* (\S+?)\(")
+
+
 def _pool_shapes(n_pool_blocks, *tails, dtype=jnp.bfloat16):
     return [((n_pool_blocks, BS) + t, dtype) for t in tails]
 
@@ -68,6 +75,25 @@ def test_paged_gqa_qwen25_3b(one_chip):
         *_pool_shapes(pool, (hkv, d), (hkv, d)),
         ((B, N_BLK), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_paged_gqa_phi4_mini_reads_pool_in_place(one_chip):
+    """phi4-mini decode: 32 slots, 24 query heads over 8 kv heads of width
+    128, a 1,920-block pool, 256-entry tables.  The kernel reads the pools
+    as they lie: no op outside it produces a pool-sized array (the
+    [N, 16, 1024] relayout the kernel once needed)."""
+    b, hq, hkv, d, pool, n = 32, 24, 8, 128, 1920, 256
+    text = _compiled_text(
+        lambda q, k, v, t, o: paged_attention(q, k, v, t, o,
+                                              interpret=False),
+        one_chip, ((b, 1, hq, d), jnp.bfloat16),
+        *_pool_shapes(pool, (hkv, d), (hkv, d)),
+        ((b, n), jnp.int32), ((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+    pool_elems = pool * BS * hkv * d
+    made = [(m.group(1), m.group(3)) for m in _HLO_OP.finditer(text)
+            if math.prod(int(x) for x in m.group(2).split(",")) == pool_elems]
+    assert made and all(op == "parameter" for _, op in made), made
 
 
 def test_paged_mla_deepseek_v3(one_chip):
