@@ -16,14 +16,64 @@ from typing import Optional, Sequence, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int                 # the router's width: every expert
     top_k: int
     d_expert: int                    # per-expert FFN hidden size
     num_shared: int = 0              # always-on shared experts (DeepSeek)
-    capacity_factor: float = 1.25
-    router: str = "softmax"          # softmax | sigmoid (DeepSeek v3)
+    capacity_factor: float = 1.25    # training dispatch only; serving is
+                                     # dropless
+    router: str = "softmax"          # softmax | noaux_tc (DeepSeek-V3:
+                                     # sigmoid scores, a selection-only
+                                     # correction bias, group-limited
+                                     # top-k)
     norm_topk: bool = True           # renormalize selected gates
     router_dtype: str = "float32"
+    n_group: int = 1                 # noaux_tc: expert groups ...
+    topk_group: int = 1              # ... of which the best this many are
+                                     # searched for the top-k
+    routed_scaling: float = 1.0      # gates scaled after normalization
+    # expert parallelism: the layer holds experts [held_from, held_from +
+    # num_held) and computes only their part of the result (0 = all)
+    held_from: int = 0
+    num_held: int = 0
+
+    def __post_init__(self):
+        if self.router not in ("softmax", "noaux_tc"):
+            raise ValueError(f"unknown router {self.router!r}")
+        held = self.num_held or self.num_experts
+        if not 0 <= self.held_from < self.held_from + held \
+                <= self.num_experts:
+            raise ValueError(f"held experts [{self.held_from}, "
+                             f"{self.held_from + held}) outside the "
+                             f"router's {self.num_experts}")
+        if self.router == "noaux_tc" and (
+                self.num_experts % self.n_group
+                or self.num_experts // self.n_group < 2
+                or not 1 <= self.topk_group <= self.n_group):
+            raise ValueError(f"noaux_tc: {self.num_experts} experts do not "
+                             f"split into {self.n_group} groups of two or "
+                             f"more with {self.topk_group} searched")
+
+    @property
+    def held(self) -> int:
+        """Experts this layer holds (its share of the router's width)."""
+        return self.num_held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V3 applies it:
+    rotary frequencies blended between extrapolated and ``factor``-fold
+    interpolated over the dims whose wavelengths fall between
+    ``beta_fast`` and ``beta_slow`` rotations of the original context,
+    cos/sin scaled by mscale(mscale) / mscale(mscale_all_dim), and the
+    softmax scale by mscale(mscale_all_dim) squared."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -34,6 +84,7 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    yarn: Optional[YaRNConfig] = None    # None: plain RoPE at rope_theta
 
 
 @dataclass(frozen=True)
@@ -180,14 +231,20 @@ class ArchConfig:
         if self.moe:
             # dropless at smoke scale (capacity ≥ T·k) so decode ≡ forward
             # exactly; production capacity_factor stays GShard-style 1.25
+            n_e = min(self.moe.num_experts, 8)
+            groups = {}
+            if self.moe.router == "noaux_tc":
+                # 4 groups of 2, the best 2 searched: still group-limited
+                groups = dict(n_group=4, topk_group=2)
             kw["moe"] = dataclasses.replace(
-                self.moe, num_experts=min(self.moe.num_experts, 8),
+                self.moe, num_experts=n_e,
                 top_k=min(self.moe.top_k, 2), d_expert=64,
-                capacity_factor=float(min(self.moe.num_experts, 8)))
+                capacity_factor=float(n_e), held_from=0, num_held=0,
+                **groups)
         if self.mla:
             kw["mla"] = MLAConfig(q_lora_rank=48, kv_lora_rank=32,
                                   qk_nope_head_dim=32, qk_rope_head_dim=16,
-                                  v_head_dim=32)
+                                  v_head_dim=32, yarn=self.mla.yarn)
         if self.ssm:
             kw["ssm"] = dataclasses.replace(self.ssm, d_state=8, d_conv=4,
                                             num_heads=2)

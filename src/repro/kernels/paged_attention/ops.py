@@ -57,21 +57,23 @@ def paged_mla_attention(q_eff, q_rope, ckv_pool, kr_pool, tables, offset, *,
     """Fused MLA absorbed decode over paged latent pools.
 
     q_eff: [B, 1, H, r] (q_nope·W_uk), q_rope: [B, 1, H, dr], ckv_pool
-    [N, bs, r], kr_pool [N, bs, 1, dr] (as cached), tables [B, n], offset
-    scalar or [B] → latent attention output [B, 1, H, r] (the caller
-    applies W_uv outside — it is a weight, not cache, contraction).
+    [N, bs, r], kr_pool [N, bs, dk] (as cached: the rotary key, zero
+    lanes past dr when dk > dr), tables [B, n], offset scalar or [B] →
+    latent attention output [B, 1, H, r] (the caller applies W_uv
+    outside — it is a weight, not cache, contraction).  The pools are
+    read in place.
     """
     B, T, H, r = q_eff.shape
     if T != 1:
         raise ValueError(
             f"paged_mla_attention is decode-only (T==1), got T={T}")
-    qe = q_eff[:, 0]
     qr = q_rope[:, 0]
-    kr = kr_pool[:, :, 0, :] if kr_pool.ndim == 4 else kr_pool
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, kr_pool.shape[-1] - qr.shape[-1])))
     lengths = _lengths(offset, B)
     interpret = (not on_tpu()) if interpret is None else interpret
-    o = paged_mla_attention_pallas(qe, qr, ckv_pool, kr, tables, lengths,
-                                   scale=scale, interpret=interpret)
+    o = paged_mla_attention_pallas(q_eff[:, 0], qr, ckv_pool, kr_pool,
+                                   tables, lengths, scale=scale,
+                                   interpret=interpret)
     return o[:, None]
 
 

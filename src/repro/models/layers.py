@@ -8,8 +8,10 @@ Pure-functional JAX: parameters are nested dicts of arrays; every layer is
   * sequences ≥ ``CHUNKED_ATTN_THRESHOLD`` use a lax.scan online-softmax
     (flash-style) path so 32k/500k shapes never materialize T×S scores —
     this is also the pure-jnp oracle for the Pallas flash kernel;
-  * MoE uses sort-based capacity dispatch (GShard capacity semantics without
-    the O(T·E·C·d) one-hot einsum) and shards experts over the "model" axis.
+  * MoE routes over every expert and computes the experts it holds (all,
+    or one chip's expert-parallel share): sort-based capacity dispatch in
+    training (GShard capacity semantics without the O(T·E·C·d) one-hot
+    einsum), dropless in serving; experts shard over the "model" axis.
 """
 
 from __future__ import annotations
@@ -22,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, YaRNConfig
 from repro.kernels.paged_attention.ops import (paged_attention,
                                                paged_mla_attention)
 from . import act_sharding as ACT
@@ -73,13 +75,47 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim)
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, y: YaRNConfig) -> jnp.ndarray:
+    """Rotary frequencies under YaRN: extrapolated (plain) for the dims that
+    turn more than ``beta_fast`` times over the original context,
+    interpolated (divided by ``factor``) for those that turn fewer than
+    ``beta_slow`` times, a linear ramp between."""
+    def turn_dim(rotations):
+        return (head_dim * math.log(y.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(turn_dim(y.beta_fast)), 0)
+    hi = min(math.ceil(turn_dim(y.beta_slow)), head_dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    plain = rope_freqs(head_dim, theta)
+    return plain / y.factor * ramp + plain * (1.0 - ramp)
+
+
+def mla_softmax_scale(m: MLAConfig) -> float:
+    """1/sqrt(dn + dr), times YaRN's mscale(mscale_all_dim) squared."""
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        scale *= _yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn: Optional[YaRNConfig] = None) -> jnp.ndarray:
     """x: [..., T, H, D]; positions: [..., T] (broadcastable)."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                          # [D/2]
+    inv = rope_freqs(d, theta) if yarn is None else yarn_freqs(d, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * inv  # [..., T, D/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        amp = (_yarn_mscale(yarn.factor, yarn.mscale)
+               / _yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -439,11 +475,25 @@ def init_mla(key, cfg: ArchConfig):
     }
 
 
+def rope_lanes(dr: int) -> int:
+    """Width at which the MLA caches keep the shared rotary key: ``dr``
+    zero-padded to whole 128-lane tiles.  A TPU keeps a 64-wide minor dim
+    in a transposed layout (or pads it to 128 lanes anyway), and the
+    decode kernel's page copies need whole lane tiles."""
+    return -(-dr // 128) * 128
+
+
+def _pad_lanes(x, width: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
               cache_offset=None, block_tables=None, paged_kernel="ref"):
-    """Latent-cache MLA. Cache stores (c_kv, k_rope): [B,S,kv_lora(+rope)];
-    paged mode pools them as [N, bs, ...] addressed via block_tables.
-    paged_kernel="pallas" fuses paged T==1 absorbed decode (no gather)."""
+    """Latent-cache MLA. Cache stores c_kv [B,S,kv_lora] and the shared
+    rotary key k_rope [B,S,rope_lanes(dr)] (no head axis, zero lanes past
+    dr); paged mode pools them as [N, bs, ...] addressed via
+    block_tables.  paged_kernel="pallas" fuses paged T==1 absorbed decode
+    (no gather, the pools read in place)."""
     m: MLAConfig = cfg.mla
     B, T, D = x.shape
     H = cfg.num_heads
@@ -452,18 +502,19 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
     q = dense(p["q_up"], rms_norm(p["q_norm"], dense(p["q_down"], x),
                                   cfg.norm_eps)).reshape(B, T, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, m.yarn)
 
     kv = dense(p["kv_down"], x)
     c_kv = rms_norm(p["kv_norm"], kv[..., :m.kv_lora_rank], cfg.norm_eps)
     k_rope = apply_rope(kv[..., None, m.kv_lora_rank:], positions,
-                        cfg.rope_theta)                       # [B,T,1,dr]
+                        cfg.rope_theta, m.yarn)[:, :, 0]      # [B,T,dr]
 
     if kv_cache is not None:
+        kr_new = _pad_lanes(k_rope, kv_cache["k_rope"].shape[-1])
         if block_tables is not None:
             ckv_pool = paged_scatter(kv_cache["c_kv"], c_kv, block_tables,
                                      cache_offset)
-            kr_pool = paged_scatter(kv_cache["k_rope"], k_rope, block_tables,
+            kr_pool = paged_scatter(kv_cache["k_rope"], kr_new, block_tables,
                                     cache_offset)
             new_cache = {"c_kv": ckv_pool, "k_rope": kr_pool}
             if T == 1 and paged_kernel == "pallas":
@@ -476,16 +527,17 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
                 q_eff = jnp.einsum("bthd,rhd->bthr", q_nope, w_k)
                 o_lat = paged_mla_attention(
                     q_eff, q_rope, ckv_pool, kr_pool, block_tables,
-                    cache_offset, scale=1.0 / math.sqrt(dn + dr))
+                    cache_offset, scale=mla_softmax_scale(m))
                 o = jnp.einsum("bthr,rhd->bthd", o_lat, w_v)
                 out = dense(p["wo"], o.reshape(B, T, H * dv))
                 return out, new_cache
             c_kv = paged_gather(ckv_pool, block_tables)
-            k_rope = paged_gather(kr_pool, block_tables)
+            k_rope = paged_gather(kr_pool, block_tables)[..., :dr]
         else:
             c_kv = _cache_update(kv_cache["c_kv"], c_kv, cache_offset)
-            k_rope = _cache_update(kv_cache["k_rope"], k_rope, cache_offset)
-            new_cache = {"c_kv": c_kv, "k_rope": k_rope}
+            kr_all = _cache_update(kv_cache["k_rope"], kr_new, cache_offset)
+            new_cache = {"c_kv": c_kv, "k_rope": kr_all}
+            k_rope = kr_all[..., :dr]
         S = c_kv.shape[1]
         pos_k = jnp.arange(S, dtype=jnp.int32)[None, :]
         pos_q = positions if positions.ndim > 1 else positions[None, :]
@@ -508,7 +560,8 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
     up = dense(p["kv_up"], c_kv).reshape(B, S, H, dn + dv)
     k_nope, v = up[..., :dn], up[..., dn:]
     k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None], (B, S, H, dr))],
+        axis=-1)
     qf = jnp.concatenate([q_nope, q_rope], axis=-1)
     if kv_cache is None:        # training
         chunk_q = QUERY_CHUNK if T >= QUERY_CHUNK_THRESHOLD else None
@@ -517,7 +570,7 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
     else:                       # decode
         chunk_q = None
     o = gqa_attention(qf, k, v, pos_q=pos_q, pos_k=pos_k, causal=True,
-                      attn_cap=None, scale=1.0 / math.sqrt(dn + dr),
+                      attn_cap=None, scale=mla_softmax_scale(m),
                       chunk_q=chunk_q)
     out = dense(p["wo"], o.reshape(B, T, H * dv))
     return out, new_cache
@@ -543,8 +596,8 @@ def _mla_absorbed_decode(p, cfg, q_nope, q_rope, c_kv, k_rope, offset):
     q_eff = jnp.einsum("bthd,rhd->bthr", q_nope, w_k)       # [B,1,H,r]
     s = jnp.einsum("bthr,bsr->bhts", q_eff, c_kv).astype(jnp.float32)
     s = s + jnp.einsum("bthd,bsd->bhts", q_rope,
-                       k_rope[:, :, 0]).astype(jnp.float32)
-    s = s / math.sqrt(dn + m.qk_rope_head_dim)
+                       k_rope).astype(jnp.float32)
+    s = s * mla_softmax_scale(m)
     s = ACT.scores_sshard(s)
     off = jnp.asarray(offset).reshape((-1, 1, 1, 1))   # scalar or per-slot [B]
     valid = jnp.arange(S, dtype=jnp.int32)[None, None, None, :] <= off
@@ -584,41 +637,72 @@ def apply_mlp(p, cfg: ArchConfig, x):
 
 
 # ---------------------------------------------------------------------------
-# Mixture of Experts (sort-based capacity dispatch, EP over "model")
+# Mixture of Experts (sort-based capacity dispatch in training, dropless
+# held experts in serving; EP over "model")
 # ---------------------------------------------------------------------------
 
 
 def init_moe(key, cfg: ArchConfig):
+    """Router over all ``num_experts`` (with the noaux_tc router's
+    selection-only correction bias as ``router/b``), the held experts'
+    stacked ``w_gate/w``, ``w_up/w`` [E_held, D, F] and ``w_down/w``
+    [E_held, F, D], and the shared expert."""
     mo: MoEConfig = cfg.moe
     dt = _dtype(cfg)
-    D, E, F = cfg.d_model, mo.num_experts, mo.d_expert
+    D, E, F = cfg.d_model, mo.held, mo.d_expert
     ks = jax.random.split(key, 5)
     scale_in = 1.0 / math.sqrt(D)
     scale_out = 1.0 / math.sqrt(F)
     p = {
-        "router": _init_dense(ks[0], D, E, jnp.float32),
-        "w_gate": (jax.random.normal(ks[1], (E, D, F), jnp.float32)
-                   * scale_in).astype(dt),
-        "w_up": (jax.random.normal(ks[2], (E, D, F), jnp.float32)
-                 * scale_in).astype(dt),
-        "w_down": (jax.random.normal(ks[3], (E, F, D), jnp.float32)
-                   * scale_out).astype(dt),
+        "router": _init_dense(ks[0], D, mo.num_experts, jnp.float32,
+                              bias=mo.router == "noaux_tc"),
+        "w_gate": {"w": (jax.random.normal(ks[1], (E, D, F), jnp.float32)
+                         * scale_in).astype(dt)},
+        "w_up": {"w": (jax.random.normal(ks[2], (E, D, F), jnp.float32)
+                       * scale_in).astype(dt)},
+        "w_down": {"w": (jax.random.normal(ks[3], (E, F, D), jnp.float32)
+                         * scale_out).astype(dt)},
     }
     if mo.num_shared:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=F * mo.num_shared)
     return p
 
 
+def _noaux_tc_select(scores, bias, mo: MoEConfig):
+    """DeepSeek-V3's group-limited choice: the bias-corrected scores pick
+    the experts, the group score is the sum of a group's best two, and
+    only the best ``topk_group`` groups are searched."""
+    T, E = scores.shape
+    G = mo.n_group
+    biased = scores + bias.astype(jnp.float32)
+    grp = lax.top_k(biased.reshape(T, G, E // G), 2)[0].sum(-1)    # [T,G]
+    _, keep = lax.top_k(grp, mo.topk_group)
+    allowed = jnp.zeros((T, G), bool).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    allowed = jnp.repeat(allowed, E // G, axis=1)                  # [T,E]
+    _, idx = lax.top_k(jnp.where(allowed, biased, -jnp.inf), mo.top_k)
+    return idx
+
+
 def _router_gates(p, mo: MoEConfig, x2d):
-    logits = (x2d.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32))
-    if mo.router == "sigmoid":                      # DeepSeek-V3 aux-free
-        scores = jax.nn.sigmoid(logits)
-    else:
+    """Top-k routing over all experts: gates [T,k] (the unbiased scores of
+    the chosen experts, normalized and scaled), expert ids [T,k], and the
+    scores [T,E] for the balance loss."""
+    # float32 at full precision, as published (a TPU's default would
+    # round both to bfloat16 and reorder near-tied experts)
+    logits = jnp.matmul(x2d.astype(jnp.float32),
+                        p["router"]["w"].astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    if mo.router == "softmax":
         scores = jax.nn.softmax(logits, axis=-1)
-    gates, idx = lax.top_k(scores, mo.top_k)        # [T,k]
+        gates, idx = lax.top_k(scores, mo.top_k)     # [T,k]
+    else:                                            # noaux_tc
+        scores = jax.nn.sigmoid(logits)
+        idx = _noaux_tc_select(scores, p["router"]["b"], mo)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
     if mo.norm_topk:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    return gates, idx, scores
+    return gates * mo.routed_scaling, idx, scores
 
 
 def moe_load_balance_loss(scores, idx, num_experts):
@@ -630,80 +714,130 @@ def moe_load_balance_loss(scores, idx, num_experts):
     return num_experts * jnp.sum(frac_prob * frac_tok)
 
 
-def apply_moe(p, cfg: ArchConfig, x):
-    """x: [B,T,D] → (y, aux_loss). Group-wise sort-based capacity dispatch.
+def _expert_act(cfg: ArchConfig):
+    return jax.nn.silu if cfg.mlp == "swiglu" else \
+        (lambda z: jax.nn.gelu(z, approximate=True))
 
-    Tokens are grouped by sequence (group = batch row), GShard-style, so the
-    dispatch buffer is [B, E, C, D] with LOCAL capacity C = ceil(T·k/E·cf):
-    the batch dim stays sharded over the data axes and experts shard over
-    "model" (EP) — no tensor ever materializes global-capacity buffers.
-    Per group:
+
+def _held_dropless(p, cfg: ArchConfig, x2d, held_gates):
+    """Every held expert over every token, weighted by its gate [T,Eh]
+    (zero where the token was not routed to it): nothing can drop."""
+    act = _expert_act(cfg)
+    h = act(jnp.einsum("td,edf->etf", x2d, p["w_gate"]["w"])) \
+        * jnp.einsum("td,edf->etf", x2d, p["w_up"]["w"])
+    y = jnp.einsum("etf,efd->etd", h, p["w_down"]["w"])
+    return jnp.einsum("te,etd->td", held_gates.astype(y.dtype), y)
+
+
+def apply_moe(p, cfg: ArchConfig, x, *, dropless: bool = False,
+              valid=None):
+    """x: [B,T,D] → (y, aux_loss, routed).
+
+    The router runs over all ``num_experts``; the layer computes the part
+    of the result that its held experts [held_from, held_from + held)
+    give, plus the shared expert.  ``routed`` [E_held] int32 counts the
+    tokens routed to each held expert (only where ``valid`` [B,T], if
+    given, is set).
+
+    ``dropless`` (serving: decode steps and prefill chunks) runs every
+    held expert over all the tokens (``_held_dropless``).  Otherwise
+    (training) a group-wise sort-based capacity dispatch: tokens are
+    grouped by sequence (group = batch row), GShard-style, so the
+    dispatch buffer is [B, E_held, C, D] with LOCAL capacity
+    C = ceil(T·k/E·cf): the batch dim stays sharded over the data axes
+    and experts shard over "model" (EP) — no tensor ever materializes
+    global-capacity buffers.  Per group:
 
       1. top-k routing → (token, expert, gate) triples
-      2. stable sort by expert; position-in-expert via segment arithmetic
-      3. scatter into [E, C, D]; batched expert GEMMs
-      4. gather back with gate weighting; overflow tokens drop (GShard).
+      2. stable sort by held expert (others last); position-in-expert via
+         segment arithmetic
+      3. scatter into [E_held, C, D]; batched expert GEMMs
+      4. gather back with gate weighting; overflow (and tokens of experts
+         not held) drop (GShard).
     """
     mo: MoEConfig = cfg.moe
     B, T, D = x.shape
-    E, K = mo.num_experts, mo.top_k
+    E, K, Eh, lo = mo.num_experts, mo.top_k, mo.held, mo.held_from
     C = max(1, int(math.ceil(T * K / E * mo.capacity_factor)))
 
-    gates, idx, scores = _router_gates(p, mo, x.reshape(B * T, D))
-    gates = gates.reshape(B, T, K)
-    idx = idx.reshape(B, T, K)
+    with jax.named_scope("moe.route"):
+        gates, idx, scores = _router_gates(p, mo, x.reshape(B * T, D))
+        local = idx - lo                                      # [BT,k]
+        is_held = (local >= 0) & (local < Eh)
+        hit = (jax.nn.one_hot(jnp.where(is_held, local, Eh), Eh + 1,
+                              dtype=jnp.int32)[..., :Eh])     # [BT,k,Eh]
+        if valid is not None:
+            hit = hit * valid.reshape(B * T, 1, 1).astype(jnp.int32)
+        routed = hit.sum((0, 1))
+        aux = moe_load_balance_loss(scores, idx, E)
 
-    def dispatch_group(xg, gate_g, idx_g):
-        """xg: [T,D]; returns (buf [E,C,D], e_sorted, slot, t_sorted, w).
+    with jax.named_scope("moe.experts"):
+        if dropless:
+            held_gates = jnp.einsum("tk,tke->te", gates,
+                                    hit.astype(gates.dtype))
+            y = _held_dropless(p, cfg, x.reshape(B * T, D), held_gates
+                               ).reshape(B, T, D)
+        else:
+            y = _capacity_dispatch(p, cfg, x, gates.reshape(B, T, K),
+                                   local.reshape(B, T, K),
+                                   is_held.reshape(B, T, K), C)
+        if mo.num_shared:
+            y = y + apply_mlp(p["shared"], cfg, x.reshape(B * T, D)
+                              ).reshape(B, T, D)
+    return y, aux, routed
 
-        The [E,C,D] buffer is built by GATHER (rows indexed by a tiny
-        [E,C+1] int32 slot→token map built with a cheap scatter), never by
+
+def _capacity_dispatch(p, cfg: ArchConfig, x, gates, local, is_held, C):
+    B, T, D = x.shape
+    K = gates.shape[-1]
+    Eh = cfg.moe.held
+
+    def dispatch_group(gate_g, local_g, held_g):
+        """Returns (slot_tok [Eh,C], e_sorted, slot, t_sorted, w).
+
+        The [Eh,C,D] buffer is built by GATHER (rows indexed by a tiny
+        [Eh,C+1] int32 slot→token map built with a cheap scatter), never by
         scattering activations into the expert-sharded dim — GSPMD can keep
         an E-sharded gather fully local, whereas a data-dependent scatter
         into a sharded dim forces replication (observed: 3.1 TiB/device on
         deepseek train before this change)."""
-        flat_e = idx_g.reshape(-1)                       # [T*K]
+        flat_e = jnp.where(held_g, local_g, Eh).reshape(-1)  # [T*K]
         flat_g = gate_g.reshape(-1)
         flat_t = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
         order = jnp.argsort(flat_e, stable=True)
         e_s, t_s, g_s = flat_e[order], flat_t[order], flat_g[order]
-        starts = jnp.searchsorted(e_s, jnp.arange(E, dtype=e_s.dtype))
-        pos = jnp.arange(T * K, dtype=jnp.int32) - starts[e_s]
-        keep = pos < C
+        starts = jnp.searchsorted(e_s, jnp.arange(Eh, dtype=e_s.dtype))
+        e_c = jnp.minimum(e_s, Eh - 1)
+        pos = jnp.arange(T * K, dtype=jnp.int32) - starts[e_c]
+        keep = (e_s < Eh) & (pos < C)
         slot = jnp.where(keep, pos, C)                   # C = overflow bin
-        slot_tok = jnp.full((E, C + 1), T, jnp.int32)    # T = "empty" row
-        slot_tok = slot_tok.at[e_s, slot].set(
-            jnp.where(keep, t_s, T))[:, :C]              # [E,C] tiny
+        slot_tok = jnp.full((Eh, C + 1), T, jnp.int32)   # T = "empty" row
+        slot_tok = slot_tok.at[e_c, slot].set(
+            jnp.where(keep, t_s, T))[:, :C]              # [Eh,C] tiny
         w = (g_s * keep.astype(jnp.float32))
-        return slot_tok, e_s, slot, t_s, w
+        return slot_tok, e_c, slot, t_s, w
 
-    slot_tok, e_s, slot, t_s, w = jax.vmap(dispatch_group)(x, gates, idx)
-    # gather rows per expert slot: [B,E,C,D]; padded row T reads zeros
+    slot_tok, e_s, slot, t_s, w = jax.vmap(dispatch_group)(gates, local,
+                                                           is_held)
+    # gather rows per expert slot: [B,Eh,C,D]; padded row T reads zeros
     x_pad = jnp.concatenate(
         [x, jnp.zeros((B, 1, D), x.dtype)], axis=1)      # [B,T+1,D]
     buf = jnp.take_along_axis(
         x_pad[:, :, None, :],
-        slot_tok.reshape(B, E * C, 1, 1).astype(jnp.int32), axis=1
-    ).reshape(B, E, C, D)
-    # buf: [B,E,C,D] — B over data axes, E over "model" (EP)
+        slot_tok.reshape(B, Eh * C, 1, 1).astype(jnp.int32), axis=1
+    ).reshape(B, Eh, C, D)
+    # buf: [B,Eh,C,D] — B over data axes, E over "model" (EP)
     buf = ACT.moe_buf(buf)
 
-    act = jax.nn.silu if cfg.mlp == "swiglu" else \
-        (lambda z: jax.nn.gelu(z, approximate=True))
-    h = act(jnp.einsum("becd,edf->becf", buf, p["w_gate"])) \
-        * jnp.einsum("becd,edf->becf", buf, p["w_up"])
+    act = _expert_act(cfg)
+    h = act(jnp.einsum("becd,edf->becf", buf, p["w_gate"]["w"])) \
+        * jnp.einsum("becd,edf->becf", buf, p["w_up"]["w"])
     y_exp = ACT.moe_buf(
-        jnp.einsum("becf,efd->becd", h, p["w_down"]))     # [B,E,C,D]
+        jnp.einsum("becf,efd->becd", h, p["w_down"]["w"]))  # [B,Eh,C,D]
 
     def combine_group(y_g, e_s, slot, t_s, w):
         contrib = y_g[e_s, jnp.minimum(slot, C - 1)] \
             * w.astype(y_g.dtype)[:, None]
         return jnp.zeros((T, D), y_g.dtype).at[t_s].add(contrib)
 
-    y = jax.vmap(combine_group)(y_exp, e_s, slot, t_s, w)
-
-    if mo.num_shared:
-        y = y + apply_mlp(p["shared"], cfg, x.reshape(B * T, D)
-                          ).reshape(B, T, D)
-    aux = moe_load_balance_loss(scores, idx.reshape(B * T, K), E)
-    return y, aux
+    return jax.vmap(combine_group)(y_exp, e_s, slot, t_s, w)

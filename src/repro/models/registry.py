@@ -81,7 +81,7 @@ def _is_routed_expert(path, leaf, cfg: ArchConfig) -> bool:
     if "ffn" not in keys or "shared" in keys or "router" in keys:
         return False
     # routed expert tensors carry the expert dim: [..., E, D, F]-shaped
-    return any(s == cfg.moe.num_experts for s in leaf.shape)
+    return any(s == cfg.moe.held for s in leaf.shape)
 
 
 def embedding_params(cfg: ArchConfig) -> int:

@@ -103,7 +103,7 @@ def _leaf_spec(cfg: ArchConfig, keys: list, shape: Tuple[int, ...],
     # ---- MoE expert tensors [E, D, F] / [E, F, D]: EP over "model"
     # (train) or over every axis (serve: 1-expert-per-chip at 256 chips) ----
     if name in ("w_gate", "w_up", "w_down") and len(body) == 3 \
-            and cfg.moe and body[0] == cfg.moe.num_experts:
+            and cfg.moe and body[0] == cfg.moe.held:
         if mode == "serve":
             if body[0] % axis_size(mesh, ep_axes) == 0:
                 return spec(ep_axes, None, None)   # full EP: 1 expert/chip

@@ -135,14 +135,18 @@ def _scatter_rec(cache, new_state, rec_rows):
 def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
                 cache=None, offset=None, prefix_len=None, block_tables=None,
                 paged_kernel="ref", rec_rows=None, update_mask=None):
-    """Returns (h, new_cache, aux_loss).
+    """Returns (h, new_cache, aux_loss, routed).
 
     ``rec_rows`` [B] addresses pooled recurrent state (serve engine): the
     block gathers each batch row's state from the pool, advances it, and
     scatters it back.  ``update_mask`` [B,T] prefix-gates the advance per
     row (chunk padding / inactive decode slots); attention layers ignore
-    it — their masked writes land on causally-hidden positions instead."""
+    it — their masked writes land on causally-hidden positions instead —
+    and an MoE layer counts only its set tokens in ``routed`` ([E_held]
+    tokens routed to each held expert; None for a dense FFN).  With a
+    cache (serving) the MoE layer is dropless."""
     aux = jnp.zeros((), jnp.float32)
+    routed = None
     if kind in XLSTM_KINDS:
         fwd = S.mlstm_forward if kind == "mlstm" else S.slstm_forward
         state = cache
@@ -151,7 +155,7 @@ def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
         h, new_state = fwd(p["cell"], cfg, h, state, update_mask=update_mask)
         if cache is not None and rec_rows is not None:
             new_state = _scatter_rec(cache, new_state, rec_rows)
-        return h, new_state, aux
+        return h, new_state, aux, routed
 
     sandwich = cfg.norm_style == "sandwich"
 
@@ -183,13 +187,15 @@ def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
     # --- FFN / MoE ---
     x = L.rms_norm(p["norm2"], h, cfg.norm_eps)
     if kind in MOE_KINDS:
-        y, aux = L.apply_moe(p["ffn"], cfg, x)
+        y, aux, routed = L.apply_moe(p["ffn"], cfg, x,
+                                     dropless=cache is not None,
+                                     valid=update_mask)
     else:
         y = L.apply_mlp(p["ffn"], cfg, x)
     if sandwich:
         y = L.rms_norm(p["post2"], y, cfg.norm_eps)
     h = h + y
-    return h, new_cache, aux
+    return h, new_cache, aux, routed
 
 
 # ---------------------------------------------------------------------------
@@ -206,7 +212,8 @@ def _block_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int):
     if kind in MLA_KINDS:
         m = cfg.mla
         return {"c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), dt),
-                "k_rope": jnp.zeros((batch, max_len, 1, m.qk_rope_head_dim), dt)}
+                "k_rope": jnp.zeros((batch, max_len,
+                                     L.rope_lanes(m.qk_rope_head_dim)), dt)}
     s = cfg.ssm
     d_in = s.expand * cfg.d_model
     if kind in MAMBA_KINDS:
@@ -351,9 +358,14 @@ def _embed(params, cfg: ArchConfig, tokens, frontend_embeds=None,
 def _run_segments(params, cfg: ArchConfig, h, *, positions, caches=None,
                   offset=None, prefix_len=None, block_tables=None,
                   paged_kernel="ref", rec_rows=None, update_mask=None):
-    """Scan each segment's stacked unit over its repeats."""
+    """Scan each segment's stacked unit over its repeats.  Returns (h,
+    new_caches, aux_total, routed): ``routed`` [L_moe, E_held] int32 holds
+    the tokens routed to each held expert of each MoE layer, in layer
+    order, when a cache is given (None without one, or with no MoE
+    layer)."""
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
+    routed = []
     for si, (unit, reps) in enumerate(cfg.segments()):
         seg_params = params["segments"][si]
         seg_cache = None if caches is None else caches[si]
@@ -361,30 +373,37 @@ def _run_segments(params, cfg: ArchConfig, h, *, positions, caches=None,
         def body(h, xs, unit=unit):
             p_unit, c_unit = xs
             aux_sum = jnp.zeros((), jnp.float32)
-            new_c = {}
+            new_c, counts = {}, []
             for j, kind in enumerate(unit):
                 c = None if c_unit is None else c_unit[f"l{j}"]
-                h, nc, aux = apply_block(
+                h, nc, aux, r = apply_block(
                     p_unit[f"l{j}"], cfg, kind, h, positions=positions,
                     cache=c, offset=offset, prefix_len=prefix_len,
                     block_tables=block_tables, paged_kernel=paged_kernel,
                     rec_rows=rec_rows, update_mask=update_mask)
                 new_c[f"l{j}"] = nc
                 aux_sum = aux_sum + aux
-            return ACT.hidden(h), (new_c, aux_sum)
+                if r is not None:
+                    counts.append(r)
+            counts = jnp.stack(counts) if counts else None
+            return ACT.hidden(h), (new_c, aux_sum, counts)
 
         if seg_cache is None:
             # drop per-layer cache outputs to keep train HLO lean
             def body_nocache(h, p_unit, unit=unit):
-                h, (_, aux_sum) = body(h, (p_unit, None), unit=unit)
+                h, (_, aux_sum, _) = body(h, (p_unit, None), unit=unit)
                 return h, aux_sum
             h, auxs = lax.scan(_maybe_remat(body_nocache), h, seg_params)
             new_caches.append(None)
         else:
-            h, (ncache, auxs) = lax.scan(body, h, (seg_params, seg_cache))
+            h, (ncache, auxs, counts) = lax.scan(body, h,
+                                                 (seg_params, seg_cache))
             new_caches.append(ncache)
+            if counts is not None:           # [reps, moe layers in unit, E]
+                routed.append(counts.reshape(-1, counts.shape[-1]))
         aux_total = aux_total + jnp.sum(auxs)
-    return h, new_caches, aux_total
+    routed = jnp.concatenate(routed) if routed else None
+    return h, new_caches, aux_total, routed
 
 
 def forward(params, cfg: ArchConfig, tokens, frontend_embeds=None,
@@ -396,8 +415,8 @@ def forward(params, cfg: ArchConfig, tokens, frontend_embeds=None,
     if positions is None:
         positions = jnp.arange(T, dtype=jnp.int32)
     prefix_len = cfg.frontend_tokens if cfg.prefix_lm else None
-    h, _, aux = _run_segments(params, cfg, h, positions=positions,
-                              prefix_len=prefix_len)
+    h, _, aux, _ = _run_segments(params, cfg, h, positions=positions,
+                                 prefix_len=prefix_len)
     h = L.rms_norm(params["final_norm"], h, cfg.norm_eps)
     logits = _head(params, cfg, h)
     return logits
@@ -453,8 +472,8 @@ def loss_fn(params, cfg: ArchConfig, batch) -> Tuple[jax.Array, Dict]:
     T = h.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)
     prefix_len = cfg.frontend_tokens if cfg.prefix_lm else None
-    h, _, aux = _run_segments(params, cfg, h, positions=positions,
-                              prefix_len=prefix_len)
+    h, _, aux, _ = _run_segments(params, cfg, h, positions=positions,
+                                 prefix_len=prefix_len)
     h = L.rms_norm(params["final_norm"], h, cfg.norm_eps)
 
     if cfg.frontend and batch.get("frontend") is not None:
@@ -478,8 +497,8 @@ def loss_fn(params, cfg: ArchConfig, batch) -> Tuple[jax.Array, Dict]:
             h_i = L.dense(mod["proj"], h_in)
             kind = "mla" if cfg.mla else "attn"
             pos_i = jnp.arange(h_i.shape[1], dtype=jnp.int32)
-            h_i, _, _ = apply_block(mod["block"], cfg, kind, h_i,
-                                    positions=pos_i)
+            h_i, _, _, _ = apply_block(mod["block"], cfg, kind, h_i,
+                                       positions=pos_i)
             h_i = L.rms_norm(mod["norm"], h_i, cfg.norm_eps)
             lbl_i = labels[:, 1 + i:]
             msk_i = (lbl_i >= 0).astype(jnp.float32)
@@ -506,16 +525,17 @@ def prefill(params, cfg: ArchConfig, tokens, cache, frontend_embeds=None):
     positions = jnp.arange(T, dtype=jnp.int32)
     prefix_len = cfg.frontend_tokens if cfg.prefix_lm else None
     offset = jnp.zeros((), jnp.int32)
-    h, new_caches, _ = _run_segments(params, cfg, h, positions=positions,
-                                     caches=cache, offset=offset,
-                                     prefix_len=prefix_len)
+    h, new_caches, _, _ = _run_segments(params, cfg, h,
+                                        positions=positions, caches=cache,
+                                        offset=offset,
+                                        prefix_len=prefix_len)
     h_last = L.rms_norm(params["final_norm"], h[:, -1:], cfg.norm_eps)
     return _head(params, cfg, h_last), new_caches, jnp.array(T, jnp.int32)
 
 
 def decode_step(params, cfg: ArchConfig, token, cache, offset,
                 block_tables=None, paged_kernel="ref", rec_rows=None,
-                active=None):
+                active=None, return_routed: bool = False):
     """token: [B,1] ints; offset: tokens-already-cached — a scalar shared by
     the batch, or a per-row [B] vector (serve slots at independent lengths
     inside one batched decode step).  ``block_tables`` [B, n] switches the
@@ -526,7 +546,11 @@ def decode_step(params, cfg: ArchConfig, token, cache, offset,
     Recurrent layers (SlotState "recurrent" backend): ``rec_rows`` [B]
     addresses each batch row's pooled state row, ``active`` [B] bool gates
     the state advance — inactive rows map to the sentinel row 0 and keep
-    it unchanged, so masked decode rows never touch live state."""
+    it unchanged, so masked decode rows never touch live state.
+
+    ``return_routed`` (MoE architectures) adds a third output: the tokens
+    of the step routed to each held expert of each MoE layer,
+    [L_moe, E_held] int32, counted over the ``active`` rows only."""
     B = token.shape[0]
     off = jnp.asarray(offset)
     if off.ndim == 1:
@@ -537,13 +561,13 @@ def decode_step(params, cfg: ArchConfig, token, cache, offset,
     if active is not None:
         update_mask = jnp.asarray(active).reshape(B, 1).astype(bool)
     h = _embed(params, cfg, token, positions=positions)
-    h, new_caches, _ = _run_segments(params, cfg, h, positions=positions,
-                                     caches=cache, offset=offset,
-                                     block_tables=block_tables,
-                                     paged_kernel=paged_kernel,
-                                     rec_rows=rec_rows,
-                                     update_mask=update_mask)
+    h, new_caches, _, routed = _run_segments(
+        params, cfg, h, positions=positions, caches=cache, offset=offset,
+        block_tables=block_tables, paged_kernel=paged_kernel,
+        rec_rows=rec_rows, update_mask=update_mask)
     h = L.rms_norm(params["final_norm"], h, cfg.norm_eps)
+    if return_routed:
+        return _head(params, cfg, h), new_caches, routed
     return _head(params, cfg, h), new_caches
 
 
@@ -586,11 +610,11 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, offset,
         update_mask = jnp.broadcast_to(
             (jnp.arange(T, dtype=jnp.int32) < v)[None, :], (B, T))
     h = _embed(params, cfg, tokens, positions=positions)
-    h, new_caches, _ = _run_segments(params, cfg, h, positions=positions,
-                                     caches=cache, offset=off,
-                                     block_tables=block_tables,
-                                     rec_rows=rec_rows,
-                                     update_mask=update_mask)
+    h, new_caches, _, _ = _run_segments(params, cfg, h, positions=positions,
+                                        caches=cache, offset=off,
+                                        block_tables=block_tables,
+                                        rec_rows=rec_rows,
+                                        update_mask=update_mask)
     if not with_logits:
         return None, new_caches
     h = L.rms_norm(params["final_norm"], h, cfg.norm_eps)

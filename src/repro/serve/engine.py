@@ -305,10 +305,14 @@ class ServeEngine:
         # backend inputs are passed as None (an empty pytree — traced away)
         pk = self.paged_kernel
         contig_kv = self.has_kv and not self.paged
+        # MoE stacks: the decode program also returns the step's tokens
+        # routed to each held expert of each MoE layer (live rows only)
+        self.moe = cfg.moe is not None
         self._decode = _named_jit(
             lambda p, tok, c, off, bt, rows, act: T.decode_step(
                 p, cfg, tok, c, off, block_tables=bt, paged_kernel=pk,
-                rec_rows=rows, active=act), "serve_decode")
+                rec_rows=rows, active=act, return_routed=self.moe),
+            "serve_decode")
 
         # admission: contiguous KV slices the slot's row, prefills one
         # chunk into it, writes it back (paged mode addresses the pool
@@ -675,16 +679,20 @@ class ServeEngine:
                 bt = self._put(jnp.asarray(self.table.block_tables()))
             if self.rec is not None:
                 rows = self._put(jnp.asarray(self.table.rec_rows()))
+            if self.rec is not None or self.moe:
                 act = self._put(jnp.asarray(active))
             tok_in = self._put(jnp.asarray(tokens))
             off_in = self._put(jnp.asarray(offsets))
         with TraceAnnotation("serve.decode.dispatch"):
-            logits, self.cache = self._decode(
+            logits, self.cache, *routed = self._decode(
                 self.params, tok_in, self.cache, off_in, bt, rows, act)
         with TraceAnnotation("serve.decode.readback"):
-            toks = np.asarray(self._sample(
+            toks, *routed = jax.device_get((self._sample(
                 logits[:, 0], self._put(jnp.asarray(req_ids)),
-                self._put(jnp.asarray(tok_idx))))
+                self._put(jnp.asarray(tok_idx))), *routed))
+            toks = np.asarray(toks)
+        if routed:
+            self.metrics.on_routed(routed[0])
         with TraceAnnotation("serve.decode.commit"):
             self.metrics.on_decode_step(int(active.sum()))
             for slot in self.table.active():

@@ -23,6 +23,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 
 class P2Quantile:
     """Jain & Chlamtac's P² streaming quantile estimator: O(1) memory,
@@ -135,6 +137,10 @@ class ServeMetrics:
     wasted_decode_tokens: int = 0    # decode tokens discarded by preemption
     queue_depth: int = 0             # admission backlog (gauge, per step)
     queue_peak: int = 0              # backlog high-water mark
+    # MoE stacks: tokens routed to each held expert of each MoE layer
+    # ([L_moe, E_held] int32), in the last decode step and in all of them
+    routed_last: Optional[np.ndarray] = None
+    routed_total: Optional[np.ndarray] = None
     # event logs for windowed trend analysis (the soak harness turns them
     # on; OFF by default so long-lived engines pay nothing):
     record_events: bool = False
@@ -227,6 +233,13 @@ class ServeMetrics:
         self.active_slot_steps += n_active
         self.decode_tokens += n_active
         self.peak_active = max(self.peak_active, n_active)
+
+    def on_routed(self, counts) -> None:
+        """The decode step's routing counter (read back with its tokens)."""
+        self.routed_last = np.asarray(counts)
+        self.routed_total = (self.routed_last.astype(np.int64)
+                             if self.routed_total is None
+                             else self.routed_total + self.routed_last)
 
     def on_token(self, req_id: int) -> None:
         self.requests[req_id].tokens_out += 1

@@ -370,13 +370,13 @@ def test_paged_mla_attention_parity(dtype):
     qe = jnp.asarray(rng.normal(size=(B, 1, H, r)), dtype=dtype)
     qr = jnp.asarray(rng.normal(size=(B, 1, H, dr)), dtype=dtype)
     ckv = jnp.asarray(rng.normal(size=(N, bs, r)), dtype=dtype)
-    krp = jnp.asarray(rng.normal(size=(N, bs, 1, dr)), dtype=dtype)
+    krp = jnp.asarray(rng.normal(size=(N, bs, dr)), dtype=dtype)
     tables = jnp.asarray(rng.integers(1, N, size=(B, n)), dtype=jnp.int32)
     tables = tables.at[2, 1:].set(0)
     offset = jnp.asarray([13, 6, 2], jnp.int32)
     scale = 1.0 / np.sqrt(32 + dr)
     out = paged_mla_attention(qe, qr, ckv, krp, tables, offset, scale=scale)
-    ref = paged_mla_attention_ref(qe[:, 0], qr[:, 0], ckv, krp[:, :, 0, :],
+    ref = paged_mla_attention_ref(qe[:, 0], qr[:, 0], ckv, krp,
                                   tables, offset + 1, scale=scale)[:, None]
     assert out.shape == (B, 1, H, r)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -390,6 +390,95 @@ def test_paged_mla_attention_parity(dtype):
                                tables, offset, scale=scale)
     assert np.array_equal(np.asarray(out, np.float32),
                           np.asarray(out2, np.float32))
+
+
+def _mla_multi_group_case(dtype, seed=0, n=40, N=120, bs=4, H=4, r=24,
+                          dr=8):
+    """The GQA multi-group rows (``_MG_OFFSETS``) over latent pools."""
+    rng = np.random.default_rng(seed)
+    B = len(_MG_OFFSETS)
+    qe = jnp.asarray(rng.normal(size=(B, 1, H, r)), dtype=dtype)
+    qr = jnp.asarray(rng.normal(size=(B, 1, H, dr)), dtype=dtype)
+    ckv = jnp.asarray(rng.normal(size=(N, bs, r)), dtype=dtype)
+    kr = jnp.asarray(rng.normal(size=(N, bs, dr)), dtype=dtype)
+    tables = np.zeros((B, n), np.int32)
+    offset = np.asarray(_MG_OFFSETS, np.int32)
+    free = list(rng.permutation(np.arange(1, N)))
+    for b in range(B):
+        if b != _MG_MASKED:
+            live = -(-int(offset[b] + 1) // bs)
+            tables[b, :live] = [free.pop() for _ in range(live)]
+    return qe, qr, ckv, kr, jnp.asarray(tables), jnp.asarray(offset)
+
+
+@pytest.fixture
+def three_mla_page_groups(monkeypatch):
+    """Hold the MLA kernel's group to 3 pages at the tiny test widths."""
+    def use(ckv, kr):
+        page = ckv.shape[1] * (ckv.shape[2] + kr.shape[2]) \
+            * ckv.dtype.itemsize
+        monkeypatch.setattr(paged_kernel, "_GROUP_BYTES", 3 * page)
+    return use
+
+
+_MLA_SCALE = 1.0 / np.sqrt(32 + 8)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_mla_attention_multi_group_parity(three_mla_page_groups,
+                                                dtype):
+    """Rows of one token, ending on a page edge, straddling a group edge,
+    filling the whole table (14 groups, the last partial), and an
+    all-sentinel masked row, which streams nothing and reads zeros."""
+    qe, qr, ckv, kr, tables, offset = _mla_multi_group_case(dtype)
+    three_mla_page_groups(ckv, kr)
+    out = paged_mla_attention(qe, qr, ckv, kr, tables, offset,
+                              scale=_MLA_SCALE)
+    ref = paged_mla_attention_ref(qe[:, 0], qr[:, 0], ckv, kr, tables,
+                                  offset + 1, scale=_MLA_SCALE)[:, None]
+    live = [b for b in range(qe.shape[0]) if b != _MG_MASKED]
+    assert out.dtype == qe.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               **_tol(dtype))
+    assert not np.asarray(out, np.float32)[_MG_MASKED].any()
+
+
+def test_paged_mla_attention_multi_group_ignores_blocks_past_length(
+        three_mla_page_groups):
+    """Poisoned sentinel, unreferenced blocks and table entries past each
+    row's length, across page groups: not one bit moves, and masked rows
+    between live ones change nothing either."""
+    qe, qr, ckv, kr, tables, offset = _mla_multi_group_case(jnp.float32,
+                                                            seed=2)
+    three_mla_page_groups(ckv, kr)
+    out = paged_mla_attention(qe, qr, ckv, kr, tables, offset,
+                              scale=_MLA_SCALE)
+    live = {int(t) for t in np.asarray(tables).ravel()} - {0}
+    poison = [i for i in range(ckv.shape[0]) if i not in live]
+    ckv2 = ckv.at[jnp.asarray(poison)].set(1e9)
+    kr2 = kr.at[jnp.asarray(poison)].set(1e9)
+    out2 = paged_mla_attention(qe, qr, ckv2, kr2,
+                               _poison_tails(tables, offset, ckv.shape[1],
+                                             poison),
+                               offset, scale=_MLA_SCALE)
+    assert np.array_equal(np.asarray(out), np.asarray(out2))
+    order = [_MG_MASKED, 0, _MG_MASKED, 1, 2, 3, _MG_MASKED]
+    idx = jnp.asarray(order)
+    mixed = np.asarray(paged_mla_attention(
+        qe[idx], qr[idx], ckv, kr, tables[idx], offset[idx],
+        scale=_MLA_SCALE))
+    got = np.stack([mixed[i] for i, b in enumerate(order)
+                    if b != _MG_MASKED])
+    assert np.array_equal(np.asarray(out)[:4], got)
+
+
+def test_mla_pages_per_group_follows_page_bytes():
+    """DeepSeek-V3's latent page: 16 tokens of c_kv (512) and the rotary
+    key (64, kept in a 128-lane tile) in bf16, 20 KiB, so 51 pages (816
+    tokens) a 1 MiB group."""
+    page = 16 * (512 + 128) * 2
+    assert paged_kernel.pages_per_group(page, 512) == 51
 
 
 # ------------------------------------------------- codec-fused tree sum --

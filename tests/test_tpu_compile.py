@@ -97,15 +97,30 @@ def test_paged_gqa_phi4_mini_reads_pool_in_place(one_chip):
 
 
 def test_paged_mla_deepseek_v3(one_chip):
-    h, r, dr = 128, 512, 64
-    pool = 1 + B * N_BLK
+    """DeepSeek-V3 decode at one chip's share: 64 slots, 128 heads over
+    the 512-wide latent and 64-wide rotary key, a 32,769-block pool,
+    512-entry tables, the pools shaped as the model caches them.  The
+    kernel reads them in place: only parameters are pool-sized (no copy
+    or relayout of the rotary-key pool before the custom call)."""
+    from repro.models import transformer as T
+    from repro.models.registry import get_config
+
+    cfg = get_config("deepseek-v3-671b")
+    b, h, r, dr, pool, n = 64, 128, 512, 64, 32_769, 512
+    cache = jax.eval_shape(lambda: T.init_paged_cache(cfg, pool, BS))
+    leaf = cache[0]["l0"]
+    ckv, kr = leaf["c_kv"].shape[1:], leaf["k_rope"].shape[1:]
     text = _compiled_text(
         lambda qe, qr, ckv, kr, t, o: paged_mla_attention(
             qe, qr, ckv, kr, t, o, scale=0.07, interpret=False),
-        one_chip, ((B, 1, h, r), jnp.bfloat16), ((B, 1, h, dr), jnp.bfloat16),
-        *_pool_shapes(pool, (r,), (1, dr)),
-        ((B, N_BLK), jnp.int32), ((B,), jnp.int32))
+        one_chip, ((b, 1, h, r), jnp.bfloat16), ((b, 1, h, dr), jnp.bfloat16),
+        (ckv, jnp.bfloat16), (kr, jnp.bfloat16),
+        ((b, n), jnp.int32), ((b,), jnp.int32))
     assert "tpu_custom_call" in text
+    sizes = {math.prod(ckv), math.prod(kr)}
+    made = [(m.group(1), m.group(3)) for m in _HLO_OP.finditer(text)
+            if math.prod(int(x) for x in m.group(2).split(",")) in sizes]
+    assert len(made) == 2 and all(op == "parameter" for _, op in made), made
 
 
 def test_tree_reduce(one_chip):
