@@ -302,7 +302,7 @@ def paged_gather(pool, tables):
     return g.reshape((B, n * bs) + pool.shape[2:])
 
 
-def paged_scatter(pool, new, tables, offset):
+def paged_scatter(pool, new, tables, offset, sentinel=PAGED_SENTINEL):
     """Write ``new`` [B,T,...] at virtual positions [offset, offset+T)
     through ``tables`` [B, n] into ``pool`` [N, bs, ...].
 
@@ -310,10 +310,10 @@ def paged_scatter(pool, new, tables, offset):
     [B] vector (slots at independent lengths) — ragged multi-token writes
     start each row's span at its own offset.  Positions beyond the
     table's span — end-padding of a short final prefill chunk, or the
-    tail of a ragged row — are redirected to the SENTINEL block instead
-    of clamping onto a live block.  Masked decode rows carry an
-    all-sentinel table row, so their writes land in the sentinel block
-    too.
+    tail of a ragged row — and positions whose table entry is the
+    SENTINEL are written to block ``sentinel`` instead of clamping onto a
+    live block.  Masked decode rows carry an all-sentinel table row, so
+    their writes land in the sentinel block too.
     """
     N, bs = pool.shape[:2]
     B, T = new.shape[:2]
@@ -327,7 +327,7 @@ def paged_scatter(pool, new, tables, offset):
             + jnp.arange(T, dtype=jnp.int32)[None, :]
     bi = pos // bs
     blk = jnp.take_along_axis(tables, jnp.clip(bi, 0, n - 1), axis=1)
-    blk = jnp.where(bi < n, blk, PAGED_SENTINEL)
+    blk = jnp.where((bi < n) & (blk != PAGED_SENTINEL), blk, sentinel)
     flat = new.reshape((B * T,) + new.shape[2:]).astype(pool.dtype)
     return pool.at[blk.reshape(-1), (pos % bs).reshape(-1)].set(flat)
 
@@ -364,6 +364,40 @@ def _cache_update(buf, new, offset):
                                            offset, axis=1)
 
 
+def _kv_write(cache, new, offset, layer, tables=None):
+    """Write one step's K/V rows into layer ``layer`` of a segment's
+    stacked cache in place; nothing outside the written rows moves.
+
+    ``cache`` {leaf: [reps, ...]} holds every repeat of the layer, ``new``
+    {leaf: [B, T, ...]} the step's rows.  Paged (``tables`` [B, n]): the
+    stack is addressed as one flat pool a leaf, [reps*N, bs, ...] (merging
+    the two major dims moves no data), through the tables offset to the
+    layer's N blocks.  Sentinel entries stay ``PAGED_SENTINEL``, so the
+    decode kernels still skip masked rows, while the writes they redirect
+    land on the layer's own block 0.  Contiguous: the layer's rows
+    [B, S, ...] take the write (``_cache_update``) and go back by an
+    indexed update along the stack.
+
+    Returns (the stacked cache, what attention reads — the flat pools, or
+    the layer's rows — and the layer's tables into the flat pools)."""
+    if tables is None:
+        rows = {name: _cache_update(
+            lax.dynamic_index_in_dim(x, layer, 0, keepdims=False),
+            new[name], offset) for name, x in cache.items()}
+        return ({name: lax.dynamic_update_index_in_dim(x, rows[name], layer,
+                                                       0)
+                 for name, x in cache.items()}, rows, None)
+    N = next(iter(cache.values())).shape[1]
+    base = layer * N
+    tables = jnp.where(tables == PAGED_SENTINEL, PAGED_SENTINEL,
+                       tables + base)
+    pools = {name: paged_scatter(x.reshape((-1,) + x.shape[2:]), new[name],
+                                 tables, offset, sentinel=base)
+             for name, x in cache.items()}
+    return ({name: pools[name].reshape(x.shape)
+             for name, x in cache.items()}, pools, tables)
+
+
 # ---------------------------------------------------------------------------
 # GQA attention layer (projections + rope + cache)
 # ---------------------------------------------------------------------------
@@ -388,16 +422,17 @@ def init_attention(key, cfg: ArchConfig):
 
 def apply_attention(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
                     cache_offset=None, window=None, prefix_len=None,
-                    block_tables=None, paged_kernel="ref"):
+                    block_tables=None, paged_kernel="ref", layer=None):
     """x: [B,T,D]. Returns (out [B,T,D], new_kv or None).
 
-    kv_cache: dict(k=[B,S,Hkv,Dh], v=...) pre-allocated ring for decode;
-    cache_offset: scalar current length (tokens already in cache).
-    block_tables: paged mode — kv_cache leaves are pools [N, bs, Hkv, Dh]
-    and [B, n] tables map virtual positions onto physical blocks.
-    paged_kernel: "pallas" routes paged T==1 decode through the fused
-    block-table kernel (no gathered [B, n*bs, ...] view); "ref" keeps the
-    gather-then-attend oracle lowering."""
+    kv_cache: the segment's stacked cache dict(k=[reps,B,S,Hkv,Dh], v=...),
+    of which this layer is repeat ``layer`` (traced); updated in place
+    (``_kv_write``).  cache_offset: scalar current length (tokens already
+    in cache).  block_tables: paged mode — kv_cache leaves are pools
+    [reps, N, bs, Hkv, Dh] and [B, n] tables map virtual positions onto
+    physical blocks.  paged_kernel: "pallas" routes paged T==1 decode
+    through the fused block-table kernel (no gathered [B, n*bs, ...]
+    view); "ref" keeps the gather-then-attend oracle lowering."""
     B, T, D = x.shape
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = dense(p["wq"], x).reshape(B, T, H, Dh)
@@ -418,26 +453,20 @@ def apply_attention(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
                           attn_cap=cfg.attn_softcap, chunk_q=chunk_q)
         new_kv = {"k": k, "v": v}
     else:
-        if block_tables is not None:
-            k_pool = paged_scatter(kv_cache["k"], k, block_tables,
-                                   cache_offset)
-            v_pool = paged_scatter(kv_cache["v"], v, block_tables,
-                                   cache_offset)
-            new_kv = {"k": k_pool, "v": v_pool}
+        new_kv, kv, tables = _kv_write(kv_cache, {"k": k, "v": v},
+                                       cache_offset, layer, block_tables)
+        if tables is not None:
             if T == 1 and paged_kernel == "pallas" and prefix_len is None:
-                # fused decode: the kernel walks block_tables directly and
+                # fused decode: the kernel walks the tables directly and
                 # streams pool blocks; the [B, n*bs, ...] gather never exists
-                o = paged_attention(q, k_pool, v_pool, block_tables,
+                o = paged_attention(q, kv["k"], kv["v"], tables,
                                     cache_offset, window=window,
                                     softcap=cfg.attn_softcap)
                 out = dense(p["wo"], o.reshape(B, T, H * Dh))
                 return out, new_kv
-            k_all = paged_gather(k_pool, block_tables)
-            v_all = paged_gather(v_pool, block_tables)
-        else:
-            k_all = _cache_update(kv_cache["k"], k, cache_offset)
-            v_all = _cache_update(kv_cache["v"], v, cache_offset)
-            new_kv = {"k": k_all, "v": v_all}
+            kv = {name: paged_gather(pool, tables)
+                  for name, pool in kv.items()}
+        k_all, v_all = kv["k"], kv["v"]
         S = k_all.shape[1]
         pos_k = jnp.arange(S, dtype=jnp.int32)[None, :]
         pos_q = positions if positions.ndim > 1 else positions[None, :]
@@ -488,12 +517,14 @@ def _pad_lanes(x, width: int):
 
 
 def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
-              cache_offset=None, block_tables=None, paged_kernel="ref"):
+              cache_offset=None, block_tables=None, paged_kernel="ref",
+              layer=None):
     """Latent-cache MLA. Cache stores c_kv [B,S,kv_lora] and the shared
     rotary key k_rope [B,S,rope_lanes(dr)] (no head axis, zero lanes past
-    dr); paged mode pools them as [N, bs, ...] addressed via
-    block_tables.  paged_kernel="pallas" fuses paged T==1 absorbed decode
-    (no gather, the pools read in place)."""
+    dr), stacked over the segment's repeats with this layer at ``layer``
+    (as in ``apply_attention``); paged mode pools them as [N, bs, ...]
+    addressed via block_tables.  paged_kernel="pallas" fuses paged T==1
+    absorbed decode (no gather, the pools read in place)."""
     m: MLAConfig = cfg.mla
     B, T, D = x.shape
     H = cfg.num_heads
@@ -511,12 +542,10 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
 
     if kv_cache is not None:
         kr_new = _pad_lanes(k_rope, kv_cache["k_rope"].shape[-1])
-        if block_tables is not None:
-            ckv_pool = paged_scatter(kv_cache["c_kv"], c_kv, block_tables,
-                                     cache_offset)
-            kr_pool = paged_scatter(kv_cache["k_rope"], kr_new, block_tables,
-                                    cache_offset)
-            new_cache = {"c_kv": ckv_pool, "k_rope": kr_pool}
+        new_cache, kv, tables = _kv_write(
+            kv_cache, {"c_kv": c_kv, "k_rope": kr_new}, cache_offset, layer,
+            block_tables)
+        if tables is not None:
             if T == 1 and paged_kernel == "pallas":
                 # fused absorbed decode in latent space, straight off the
                 # pools (the weight absorption of _mla_absorbed_decode with
@@ -526,18 +555,14 @@ def apply_mla(p, cfg: ArchConfig, x, *, positions, kv_cache=None,
                 w_k, w_v = w_up[..., :dn], w_up[..., dn:]
                 q_eff = jnp.einsum("bthd,rhd->bthr", q_nope, w_k)
                 o_lat = paged_mla_attention(
-                    q_eff, q_rope, ckv_pool, kr_pool, block_tables,
+                    q_eff, q_rope, kv["c_kv"], kv["k_rope"], tables,
                     cache_offset, scale=mla_softmax_scale(m))
                 o = jnp.einsum("bthr,rhd->bthd", o_lat, w_v)
                 out = dense(p["wo"], o.reshape(B, T, H * dv))
                 return out, new_cache
-            c_kv = paged_gather(ckv_pool, block_tables)
-            k_rope = paged_gather(kr_pool, block_tables)[..., :dr]
-        else:
-            c_kv = _cache_update(kv_cache["c_kv"], c_kv, cache_offset)
-            kr_all = _cache_update(kv_cache["k_rope"], kr_new, cache_offset)
-            new_cache = {"c_kv": c_kv, "k_rope": kr_all}
-            k_rope = kr_all[..., :dr]
+            kv = {name: paged_gather(pool, tables)
+                  for name, pool in kv.items()}
+        c_kv, k_rope = kv["c_kv"], kv["k_rope"][..., :dr]
         S = c_kv.shape[1]
         pos_k = jnp.arange(S, dtype=jnp.int32)[None, :]
         pos_q = positions if positions.ndim > 1 else positions[None, :]

@@ -117,26 +117,37 @@ def init_block(key, cfg: ArchConfig, kind: str):
     return p
 
 
-def _gather_rec(cache, rec_rows):
-    """View of the pooled recurrent state at rows ``rec_rows`` [B]."""
-    return jax.tree.map(lambda x: x[rec_rows], cache)
+def _gather_rec(cache, layer, rec_rows):
+    """Layer ``layer``'s recurrent state, read from the segment's stacked
+    leaves [reps, R, ...]: the pooled rows ``rec_rows`` [B] (serve engine)
+    or, without them, every row."""
+    rows = slice(None) if rec_rows is None else rec_rows
+    return jax.tree.map(lambda x: x[layer, rows], cache)
 
 
-def _scatter_rec(cache, new_state, rec_rows):
-    """Write per-row state back into the pool.  Rows gated off by the
-    update mask carry their own gathered value, so duplicate sentinel
-    indices (row 0 for every masked batch row) all write identical bits —
-    the scatter stays deterministic."""
+def _scatter_rec(cache, new_state, layer, rec_rows):
+    """Write layer ``layer``'s advanced state back into the stacked leaves
+    in place.  Rows gated off by the update mask carry their own gathered
+    value, so duplicate sentinel indices (row 0 for every masked batch
+    row) all write identical bits — the scatter stays deterministic."""
+    if rec_rows is None:
+        return jax.tree.map(
+            lambda full, ns: lax.dynamic_update_index_in_dim(
+                full, ns.astype(full.dtype), layer, 0), cache, new_state)
     return jax.tree.map(
-        lambda full, ns: full.at[rec_rows].set(ns.astype(full.dtype)),
+        lambda full, ns: full.at[layer, rec_rows].set(ns.astype(full.dtype)),
         cache, new_state)
 
 
 def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
                 cache=None, offset=None, prefix_len=None, block_tables=None,
-                paged_kernel="ref", rec_rows=None, update_mask=None):
+                paged_kernel="ref", rec_rows=None, update_mask=None,
+                layer=None):
     """Returns (h, new_cache, aux_loss, routed).
 
+    ``cache`` is the segment's stacked cache for this layer's position in
+    the unit (leaves [reps, ...]), of which the block reads and writes
+    repeat ``layer`` (traced) in place and returns the whole stack.
     ``rec_rows`` [B] addresses pooled recurrent state (serve engine): the
     block gathers each batch row's state from the pool, advances it, and
     scatters it back.  ``update_mask`` [B,T] prefix-gates the advance per
@@ -149,12 +160,10 @@ def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
     routed = None
     if kind in XLSTM_KINDS:
         fwd = S.mlstm_forward if kind == "mlstm" else S.slstm_forward
-        state = cache
-        if cache is not None and rec_rows is not None:
-            state = _gather_rec(cache, rec_rows)
+        state = None if cache is None else _gather_rec(cache, layer, rec_rows)
         h, new_state = fwd(p["cell"], cfg, h, state, update_mask=update_mask)
-        if cache is not None and rec_rows is not None:
-            new_state = _scatter_rec(cache, new_state, rec_rows)
+        if cache is not None:
+            new_state = _scatter_rec(cache, new_state, layer, rec_rows)
         return h, new_state, aux, routed
 
     sandwich = cfg.norm_style == "sandwich"
@@ -166,20 +175,19 @@ def apply_block(p, cfg: ArchConfig, kind: str, h, *, positions,
         mix, new_cache = L.apply_attention(
             p["attn"], cfg, x, positions=positions, kv_cache=cache,
             cache_offset=offset, window=window, prefix_len=prefix_len,
-            block_tables=block_tables, paged_kernel=paged_kernel)
+            block_tables=block_tables, paged_kernel=paged_kernel,
+            layer=layer)
     elif kind in MLA_KINDS:
         mix, new_cache = L.apply_mla(p["attn"], cfg, x, positions=positions,
                                      kv_cache=cache, cache_offset=offset,
                                      block_tables=block_tables,
-                                     paged_kernel=paged_kernel)
+                                     paged_kernel=paged_kernel, layer=layer)
     else:  # mamba
-        state = cache
-        if cache is not None and rec_rows is not None:
-            state = _gather_rec(cache, rec_rows)
+        state = None if cache is None else _gather_rec(cache, layer, rec_rows)
         mix, new_cache = S.mamba_forward(p["mamba"], cfg, x, state,
                                          update_mask=update_mask)
-        if cache is not None and rec_rows is not None:
-            new_cache = _scatter_rec(cache, new_cache, rec_rows)
+        if cache is not None:
+            new_cache = _scatter_rec(cache, new_cache, layer, rec_rows)
     if sandwich:
         mix = L.rms_norm(p["post1"], mix, cfg.norm_eps)
     h = h + mix
@@ -362,7 +370,12 @@ def _run_segments(params, cfg: ArchConfig, h, *, positions, caches=None,
     new_caches, aux_total, routed): ``routed`` [L_moe, E_held] int32 holds
     the tokens routed to each held expert of each MoE layer, in layer
     order, when a cache is given (None without one, or with no MoE
-    layer)."""
+    layer).
+
+    A segment's stacked cache rides in the scan's carry, and repeat ``i``
+    reads and writes layer ``i`` of each leaf in place: a step moves only
+    the rows it writes, never a whole layer's pool (a cache given as the
+    scan's xs / ys would be sliced out and written anew every layer)."""
     aux_total = jnp.zeros((), jnp.float32)
     new_caches = []
     routed = []
@@ -370,8 +383,7 @@ def _run_segments(params, cfg: ArchConfig, h, *, positions, caches=None,
         seg_params = params["segments"][si]
         seg_cache = None if caches is None else caches[si]
 
-        def body(h, xs, unit=unit):
-            p_unit, c_unit = xs
+        def unit_step(h, p_unit, c_unit=None, i=None, unit=unit):
             aux_sum = jnp.zeros((), jnp.float32)
             new_c, counts = {}, []
             for j, kind in enumerate(unit):
@@ -380,24 +392,31 @@ def _run_segments(params, cfg: ArchConfig, h, *, positions, caches=None,
                     p_unit[f"l{j}"], cfg, kind, h, positions=positions,
                     cache=c, offset=offset, prefix_len=prefix_len,
                     block_tables=block_tables, paged_kernel=paged_kernel,
-                    rec_rows=rec_rows, update_mask=update_mask)
+                    rec_rows=rec_rows, update_mask=update_mask, layer=i)
                 new_c[f"l{j}"] = nc
                 aux_sum = aux_sum + aux
                 if r is not None:
                     counts.append(r)
             counts = jnp.stack(counts) if counts else None
-            return ACT.hidden(h), (new_c, aux_sum, counts)
+            return ACT.hidden(h), new_c, aux_sum, counts
 
         if seg_cache is None:
             # drop per-layer cache outputs to keep train HLO lean
             def body_nocache(h, p_unit, unit=unit):
-                h, (_, aux_sum, _) = body(h, (p_unit, None), unit=unit)
+                h, _, aux_sum, _ = unit_step(h, p_unit, unit=unit)
                 return h, aux_sum
             h, auxs = lax.scan(_maybe_remat(body_nocache), h, seg_params)
             new_caches.append(None)
         else:
-            h, (ncache, auxs, counts) = lax.scan(body, h,
-                                                 (seg_params, seg_cache))
+            def body(carry, xs, unit=unit):
+                h, c_unit = carry
+                p_unit, i = xs
+                h, c_unit, aux_sum, counts = unit_step(h, p_unit, c_unit, i,
+                                                       unit=unit)
+                return (h, c_unit), (aux_sum, counts)
+            (h, ncache), (auxs, counts) = lax.scan(
+                body, (h, seg_cache),
+                (seg_params, jnp.arange(reps, dtype=jnp.int32)))
             new_caches.append(ncache)
             if counts is not None:           # [reps, moe layers in unit, E]
                 routed.append(counts.reshape(-1, counts.shape[-1]))

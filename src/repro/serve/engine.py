@@ -144,11 +144,60 @@ def _check_arch(cfg: ArchConfig) -> None:
             "(requests are token-only)")
 
 
-def _named_jit(fn, name: str):
+def _named_jit(fn, name: str, donate=()):
     """``jax.jit(fn)`` under a stable program name: ``jit_<name>`` in a
-    profiler trace and in the compiled module."""
+    profiler trace and in the compiled module.  ``donate`` lists the
+    arguments the program consumes: the serve programs take the cache
+    there, so its output aliases the input and the pool is updated in
+    place instead of written anew every call."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate)
+
+
+def serve_programs(cfg: ArchConfig, *, paged_kernel: str, contig_kv: bool):
+    """The engine's jitted cache programs by name.  Each takes the cache
+    as a donated argument and returns its successor, which aliases it: a
+    call updates the pool in place.
+
+    One decode / admit pair serves every backend mix: unused backend
+    inputs are passed as None (an empty pytree — traced away).  MoE stacks:
+    the decode program also returns the step's tokens routed to each held
+    expert of each MoE layer (live rows only).  Admission: contiguous KV
+    (``contig_kv``) slices the slot's row, prefills one chunk into it,
+    writes it back (paged mode addresses the pool through the slot's
+    [1, n_max] table row instead; recurrent state is row-addressed in
+    place via ``rec_row``).  Interior chunks only feed the cache, so they
+    skip the full-vocab head projection (the dominant admission FLOPs at
+    real vocab sizes)."""
+    moe = cfg.moe is not None
+
+    def admit(with_logits):
+        def fn(p, c, tokens, slot, offset, table, rec_row, valid):
+            sub = T.take_state(cfg, c, slot) if contig_kv else c
+            logits, sub = T.prefill_chunk(
+                p, cfg, tokens, sub, offset, with_logits=with_logits,
+                block_tables=table, rec_rows=rec_row, valid=valid)
+            if contig_kv:
+                return logits, T.write_state(cfg, c, sub, slot)
+            return logits, sub
+        return fn
+
+    fns = {
+        "serve_decode": (
+            lambda p, tok, c, off, bt, rows, act: T.decode_step(
+                p, cfg, tok, c, off, block_tables=bt,
+                paged_kernel=paged_kernel, rec_rows=rows, active=act,
+                return_routed=moe), 2),
+        "serve_prefill": (admit(True), 1),
+        "serve_prefill_quiet": (admit(False), 1),
+        "serve_reset": (
+            lambda c, slot, row: T.reset_slot_state(cfg, c, slot=slot,
+                                                    rec_row=row), 0),
+        "serve_copy_block": (
+            lambda c, src, dst: T.copy_block(c, src, dst), 0),
+    }
+    return {name: _named_jit(fn, name, donate=(arg,))
+            for name, (fn, arg) in fns.items()}
 
 
 def _make_sampler(base_key, temperature: float):
@@ -301,47 +350,24 @@ class ServeEngine:
                     cache)
         self.cache = cache
 
-        # One jitted decode / admit pair serves every backend mix: unused
-        # backend inputs are passed as None (an empty pytree — traced away)
-        pk = self.paged_kernel
-        contig_kv = self.has_kv and not self.paged
-        # MoE stacks: the decode program also returns the step's tokens
-        # routed to each held expert of each MoE layer (live rows only)
         self.moe = cfg.moe is not None
-        self._decode = _named_jit(
-            lambda p, tok, c, off, bt, rows, act: T.decode_step(
-                p, cfg, tok, c, off, block_tables=bt, paged_kernel=pk,
-                rec_rows=rows, active=act, return_routed=self.moe),
-            "serve_decode")
-
-        # admission: contiguous KV slices the slot's row, prefills one
-        # chunk into it, writes it back (paged mode addresses the pool
-        # through the slot's [1, n_max] table row instead; recurrent state
-        # is row-addressed in place via ``rec_row``).  Interior chunks only
-        # feed the cache, so they skip the full-vocab head projection (the
-        # dominant admission FLOPs at real vocab sizes)
-        def admit(with_logits, name):
-            def fn(p, c, tokens, slot, offset, table, rec_row, valid):
-                sub = T.take_state(cfg, c, slot) if contig_kv else c
-                logits, sub = T.prefill_chunk(
-                    p, cfg, tokens, sub, offset, with_logits=with_logits,
-                    block_tables=table, rec_rows=rec_row, valid=valid)
-                if contig_kv:
-                    return logits, T.write_state(cfg, c, sub, slot)
-                return logits, sub
-            return _named_jit(fn, name)
-        self._admit = admit(True, "serve_prefill")
-        self._admit_quiet = admit(False, "serve_prefill_quiet")
-        self._reset = _named_jit(
-            lambda c, slot, row: T.reset_slot_state(cfg, c, slot=slot,
-                                                    rec_row=row),
-            "serve_reset")
+        progs = serve_programs(cfg, paged_kernel=self.paged_kernel,
+                               contig_kv=self.has_kv and not self.paged)
+        self._decode = progs["serve_decode"]
+        self._admit = progs["serve_prefill"]
+        self._admit_quiet = progs["serve_prefill_quiet"]
+        self._reset = progs["serve_reset"]
         if self.paged:
-            self._copy = _named_jit(
-                lambda c, src, dst: T.copy_block(c, src, dst),
-                "serve_copy_block")
+            self._copy = progs["serve_copy_block"]
         self._sample = _named_jit(
             _make_sampler(self._key, ecfg.temperature), "serve_sample")
+
+    def _took_cache(self, old) -> None:
+        """Count a program call that took the cache ``old``: in place when
+        the call consumed its buffers (a donated input is deleted at
+        dispatch; a refused donation leaves it alive, and JAX warns).  A
+        host check: nothing waits for the device."""
+        self.metrics.on_cache_call(jax.tree.leaves(old)[0].is_deleted())
 
     def _put(self, x):
         if self._data_spec is None:
@@ -442,7 +468,9 @@ class ServeEngine:
                 continue        # a preemption may even have unshared blk
             if copied:
                 if need_copy:
-                    self.cache = self._copy(self.cache, blk, new)
+                    old = self.cache
+                    self.cache = self._copy(old, blk, new)
+                    self._took_cache(old)
                 slot.blocks[block_idx] = new
             return True
 
@@ -524,7 +552,9 @@ class ServeEngine:
                     slot_idx = (slot.index if self.has_kv and not self.paged
                                 else None)
                     row = slot.rec_row if self.rec is not None else None
-                    self.cache = self._reset(self.cache, slot_idx, row)
+                    old = self.cache
+                    self.cache = self._reset(old, slot_idx, row)
+                    self._took_cache(old)
 
     def _finish(self, slot) -> None:
         req = slot.request
@@ -621,7 +651,9 @@ class ServeEngine:
                       jnp.asarray(start, jnp.int32), table, rec_row,
                       None if valid is None else jnp.asarray(valid, jnp.int32))
         with TraceAnnotation("serve.prefill.dispatch"):
-            logits, self.cache = admit(self.params, self.cache, *inputs)
+            old = self.cache
+            logits, self.cache = admit(self.params, old, *inputs)
+        self._took_cache(old)
         slot.prefill_pos += min(remaining, C)
         slot.length = slot.prefill_pos
         self.metrics.on_prefill_chunk(min(remaining, C))
@@ -684,8 +716,10 @@ class ServeEngine:
             tok_in = self._put(jnp.asarray(tokens))
             off_in = self._put(jnp.asarray(offsets))
         with TraceAnnotation("serve.decode.dispatch"):
+            old = self.cache
             logits, self.cache, *routed = self._decode(
-                self.params, tok_in, self.cache, off_in, bt, rows, act)
+                self.params, tok_in, old, off_in, bt, rows, act)
+        self._took_cache(old)
         with TraceAnnotation("serve.decode.readback"):
             toks, *routed = jax.device_get((self._sample(
                 logits[:, 0], self._put(jnp.asarray(req_ids)),

@@ -137,6 +137,10 @@ class ServeMetrics:
     wasted_decode_tokens: int = 0    # decode tokens discarded by preemption
     queue_depth: int = 0             # admission backlog (gauge, per step)
     queue_peak: int = 0              # backlog high-water mark
+    # serve-program calls that took the cache, and those that consumed it
+    # in place (donated: the output pool aliases the input)
+    cache_calls: int = 0
+    cache_donated: int = 0
     # MoE stacks: tokens routed to each held expert of each MoE layer
     # ([L_moe, E_held] int32), in the last decode step and in all of them
     routed_last: Optional[np.ndarray] = None
@@ -241,6 +245,12 @@ class ServeMetrics:
                              if self.routed_total is None
                              else self.routed_total + self.routed_last)
 
+    def on_cache_call(self, donated: bool) -> None:
+        """A serve program took the cache; ``donated``: it consumed the
+        buffers in place."""
+        self.cache_calls += 1
+        self.cache_donated += bool(donated)
+
     def on_token(self, req_id: int) -> None:
         self.requests[req_id].tokens_out += 1
 
@@ -326,6 +336,14 @@ class ServeMetrics:
             return 0.0
         return self.decode_tokens / self.decode_steps
 
+    @property
+    def cache_donated_share(self) -> float:
+        """Share of the cache-taking program calls that updated the cache
+        in place (1.0 unless the runtime refused a donation)."""
+        if self.cache_calls == 0:
+            return 0.0
+        return self.cache_donated / self.cache_calls
+
     def ttfts(self) -> List[float]:
         return sorted(r.ttft_s for r in self.requests.values()
                       if r.ttft_s is not None)
@@ -359,6 +377,7 @@ class ServeMetrics:
             "wasted_decode_tokens": self.wasted_decode_tokens,
             "first_tokens": self.first_tokens,
             "queue_peak": self.queue_peak,
+            "cache_donated_share": self.cache_donated_share,
             "ttft_mean_s": (sum(ttfts) / len(ttfts)) if ttfts else float("nan"),
             "ttft_p50_s": self._pct(ttfts, 0.50),
             "ttft_p95_s": self._pct(ttfts, 0.95),

@@ -148,3 +148,75 @@ def test_int8_coded_tree_reduce(one_chip):
                                        interpret=False),
         one_chip, ((4, nb, 128), jnp.int8), ((4, nb, 1), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def _serve_cell(model):
+    """The serve cells' widths at two layers: (cfg, slots, table width,
+    chunk, pool blocks).  phi4-mini: two GQA layers stacked in one
+    segment, 32 slots of 4,096 positions, a 1,920-block pool.
+    DeepSeek-V3: one dense and one MoE layer holding 8 of 256 experts,
+    64 slots of 8,192 positions, a 32,769-block latent pool."""
+    import dataclasses
+
+    from repro.models.registry import get_config
+
+    cfg = get_config(model)
+    if cfg.moe is None:
+        cfg = dataclasses.replace(cfg, num_layers=2,
+                                  layer_pattern=("attn",) * 2)
+        return cfg, 32, 4096 // BS, 256, 1920
+    cfg = dataclasses.replace(cfg, num_layers=2,
+                              layer_pattern=("mla", "mla_moe"),
+                              moe=dataclasses.replace(cfg.moe, num_held=8))
+    return cfg, 64, 8192 // BS, 256, 32_769
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill_quiet"])
+@pytest.mark.parametrize("model", ["phi4-mini-3.8b", "deepseek-v3-671b"])
+def test_serve_programs_update_the_pool_in_place(one_chip, monkeypatch,
+                                                 model, program):
+    """The engine's own decode and interior-chunk prefill programs, with
+    the donation the engine gives them, update the paged pool in place:
+    the output aliases the whole pool, and no temporary grows with it —
+    compiled again over a 16-block pool, the temporaries shrink by less
+    than one layer's pool, so no layer (let alone the stack) is copied.
+    (The quiet prefill: a final chunk's float32 logits, 256 x 200,064,
+    outweigh one phi4 layer's pool; MLA prefill's up-projected K/V are
+    temporaries of their own that do not depend on the pool.)"""
+    import repro.kernels.paged_attention.ops as ops
+    from repro.models import transformer as T
+    from repro.serve.engine import serve_programs
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the real kernels
+    cfg, slots, n, chunk, blocks = _serve_cell(model)
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    params = sds(jax.eval_shape(lambda: T.init_params(cfg,
+                                                      jax.random.key(0))))
+
+    def memory(num_blocks):
+        cache = sds(jax.eval_shape(
+            lambda: T.init_paged_cache(cfg, num_blocks, BS)))
+        prog = serve_programs(cfg, paged_kernel="pallas",
+                              contig_kv=False)[program]
+        if program == "serve_decode":
+            act = jax.ShapeDtypeStruct((slots,), jnp.bool_,
+                                       sharding=one_chip)
+            args = (params, i32(slots, 1), cache, i32(slots), i32(slots, n),
+                    None, act if cfg.moe else None)
+        else:
+            args = (params, cache, i32(1, chunk), i32(), i32(), i32(1, n),
+                    None, None)
+        pool = sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(cache))
+        return pool, prog.lower(*args).compile().memory_analysis()
+
+    pool, mem = memory(blocks)
+    _, small = memory(16)
+    assert mem.alias_size_in_bytes >= pool, (mem.alias_size_in_bytes, pool)
+    grew = mem.temp_size_in_bytes - small.temp_size_in_bytes
+    assert grew < pool // cfg.num_layers, (grew, pool // cfg.num_layers)
